@@ -34,6 +34,17 @@ def _band(name: str, lo, hi, values, allow_slack=0.0) -> str:
             f"{'OK' if ok else 'BELOW BAND'}")
 
 
+def _device_fields() -> dict:
+    """The device the jax sections ran on, as jax reports it.  Called at
+    the end of a run: importing jax earlier would switch the process pool
+    from fork to spawn."""
+    import jax
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
+
+
 def main() -> None:
     from repro.core import (BATCH_BACKENDS, DEFAULT_CACHE,
                             DEFAULT_STAGE_CACHE, PNR_BACKENDS,
@@ -64,6 +75,8 @@ def main() -> None:
                          "(numpy).  The pnr section always runs both "
                          "kernels head-to-head.")
     args = ap.parse_args()
+    from repro.launch.jax_cache import use_compile_cache
+    use_compile_cache()
     backend_pnr = args.backend_pnr or (
         pnr_backend() if os.environ.get("CASCADE_PNR_BACKEND") else None)
 
@@ -184,6 +197,7 @@ def main() -> None:
         "workers": args.workers or worker_count(),
         "disk_cache": not args.no_disk_cache,
         "cpu_count": os.cpu_count(),
+        **_device_fields(),
         "python": sys.version.split()[0],
         "total_seconds": round(total, 2),
         "sections": sections,

@@ -105,11 +105,7 @@ def bench_pipelining(fast: bool = False) -> List[Dict]:
                             lower_design)
 
     compiler = CascadeCompiler(cache=CompileCache())
-    try:
-        import jax  # noqa: F401
-        backends = ("numpy", "jax")
-    except Exception:                     # pragma: no cover - env dependent
-        backends = ("numpy",)
+    backends = ("numpy", "jax")
 
     rows: List[Dict] = []
     for app, mult in (FAST_APPS if fast else BENCH_APPS):

@@ -25,7 +25,7 @@ def serve_one(arch: str, b=4, plen=32, gen=16):
     model = LM(cfg)
     shd.set_rules(S.rules_for(cfg))
     mesh = make_smoke_mesh()
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         cache = model.init_cache(b, plen + gen)
         prefill = jax.jit(S.make_prefill_step(model))

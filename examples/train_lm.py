@@ -61,7 +61,7 @@ def main():
     ckpt_dir = tempfile.mkdtemp(prefix="train_lm_ckpt_")
     mgr = CheckpointManager(ckpt_dir, keep=2, async_save=True)
 
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         st_sh, b_sh = S.train_shardings(model, opt_cfg, mesh, shape)
         step_fn = jax.jit(S.make_train_step(model, opt_cfg),
                           in_shardings=(st_sh, b_sh),

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import copy
 import multiprocessing
+import os
 import pickle
 import sys
 import time
@@ -237,6 +238,21 @@ def resident_config(cfg: "PassConfig", region: Region,
 #: job misses every cache tier (the only case where multi-core pays for the
 #: fork/pickle overhead), else "thread".
 BATCH_BACKENDS = ("auto", "thread", "process")
+
+
+def _uses_device(cfg: "PassConfig") -> bool:
+    """Whether ``cfg`` runs a kernel through jax.  Such a compile stays in
+    the process that owns the accelerator: a pool worker would try to
+    open the device a second time."""
+    return "jax" in (cfg.pnr_backend, cfg.sta_backend)
+
+
+def _host_only_worker() -> None:
+    """Pool initializer: keep a worker's jax (if it loads one) on the CPU,
+    so no worker can ever claim the parent's accelerator."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
 
 
 def _process_context():
@@ -672,6 +688,11 @@ class CascadeCompiler:
         * ``"auto"`` (default) — ``"process"`` when more than one job
           misses every cache tier, else ``"thread"``.
 
+        A job whose config runs a jax kernel (``pnr_backend`` or
+        ``sta_backend`` ``"jax"``) always compiles on the thread path, in
+        this process: only one process may hold the accelerator.  Process
+        workers run with ``JAX_PLATFORMS=cpu``.
+
         Jobs whose config schedules the ``pareto_frontier`` pass with more
         than one sweep point are *fanned out*: the shared prefix compiles
         (or stage-cache-resumes) once in the parent, and the individual
@@ -743,7 +764,10 @@ class CascadeCompiler:
             max(len(norm), sum(fan_points.values())))
         chosen = backend
         if chosen == "auto":
-            effective = len(plain) + sum(fan_points.values())
+            effective = (
+                sum(not _uses_device(norm[i][1]) for i in plain)
+                + sum(n for i, n in fan_points.items()
+                      if not _uses_device(norm[i][1])))
             chosen = "process" if effective > 1 else "thread"
 
         proc: List[int] = []
@@ -757,6 +781,9 @@ class CascadeCompiler:
                 env_picklable = False     # whole worker payload must cross
             proc, threaded = [], []
             for i in plain:
+                if _uses_device(norm[i][1]):
+                    threaded.append(i)
+                    continue
                 try:
                     if not env_picklable:
                         raise TypeError("compiler env not picklable")
@@ -778,7 +805,8 @@ class CascadeCompiler:
             if proc:
                 with ProcessPoolExecutor(
                         max_workers=min(workers, len(proc)),
-                        mp_context=_process_context()) as ex:
+                        mp_context=_process_context(),
+                        initializer=_host_only_worker) as ex:
                     futs = {i: ex.submit(_compile_job_in_worker, i,
                                          norm[i][0], norm[i][1], norm[i][2],
                                          verify, self.fabric, self.timing,
@@ -805,8 +833,9 @@ class CascadeCompiler:
                         norm[i][0], norm[i][1], unroll=norm[i][2],
                         verify=verify, use_cache=use_cache, _key=keys[i],
                         _skip_lookup=True,
-                        _point_map=self._pool_point_map(chosen, workers, i,
-                                                        norm[i][0].name))
+                        _point_map=self._pool_point_map(
+                            "thread" if _uses_device(norm[i][1]) else chosen,
+                            workers, i, norm[i][0].name))
                 except BatchCompileError:
                     raise
                 except Exception as e:
@@ -863,7 +892,8 @@ class CascadeCompiler:
                                              points, kwargs)
                 with ProcessPoolExecutor(
                         max_workers=min(workers, len(points)),
-                        mp_context=_process_context()) as ex:
+                        mp_context=_process_context(),
+                        initializer=_host_only_worker) as ex:
                     futs = [(p, ex.submit(_frontier_point_in_worker, blob,
                                           p[0], p[1], kwargs, job_index,
                                           app_name))

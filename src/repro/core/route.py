@@ -14,8 +14,9 @@ means the same thing at both PnR stages), while ``"jax"`` swaps in the
 batched wavefront relaxation of :mod:`repro.core.route_jax`, which routes
 every dirty driver of a width class in one jitted call.  Both backends
 produce the same ``driver -> branch -> tile path`` map and share the
-finalization below (region containment check, hop construction, register
-distribution), so post-route legality is checked identically.
+finalization below (hop construction, register distribution and the
+:func:`check_legal` backstop), so post-route legality is checked
+identically.
 
 After routing, each branch distributes its ``n_regs`` pipelining registers
 evenly along its hops (post-PnR pipelining later adds registers at chosen
@@ -202,21 +203,51 @@ def _finalize(nl: Netlist, placement: Dict[str, Tile], fabric: Fabric,
               by_driver: Dict[str, List[Branch]],
               tree_paths: Dict[str, Dict[Tuple[str, str, int], List[Tile]]],
               region: Optional[Region]) -> RoutedDesign:
-    """Shared post-route step for every backend: region containment check,
-    hop construction, register distribution."""
+    """Shared post-route step for every backend: hop construction,
+    register distribution, and the :func:`check_legal` backstop."""
     routes: Dict[Tuple[str, str, int], RoutedBranch] = {}
     for drv, paths in tree_paths.items():
         for b in by_driver[drv]:
             pth = paths[b.key]
-            if region is not None:
-                stray = [t for t in pth if not region.contains(t)]
-                if stray:
-                    raise RuntimeError(
-                        f"{nl.name}: route {drv} -> {b.sink} left region "
-                        f"{region} at {stray[:3]}")
             hops = [Hop(pth[i], pth[i + 1]) for i in range(len(pth) - 1)]
             rb = RoutedBranch(branch=b, hops=hops)
             rb.distribute_registers()
             routes[b.key] = rb
-    return RoutedDesign(netlist=nl, placement=placement, routes=routes,
-                        fabric=fabric)
+    design = RoutedDesign(netlist=nl, placement=placement, routes=routes,
+                          fabric=fabric)
+    check_legal(design, region)
+    return design
+
+
+def check_legal(design: RoutedDesign, region: Optional[Region] = None
+                ) -> None:
+    """Raise ``RuntimeError`` unless every route of ``design`` runs from
+    its driver's tile to its sink's tile over adjacent tiles, stays inside
+    ``region`` (when given), and no boundary carries more routing trees
+    than ``fabric.track_capacity`` allows for its width class."""
+    nl, fabric, placement = design.netlist, design.fabric, design.placement
+    usage: Dict[Tuple[Tile, Tile, int], Set[str]] = {}
+    for rb in design.routes.values():
+        b = rb.branch
+        tiles = [placement[b.driver]] + [h.dst for h in rb.hops]
+        if tiles[-1] != placement[b.sink] or any(
+                h.src != t for h, t in zip(rb.hops, tiles)):
+            raise RuntimeError(f"{nl.name}: route {b.driver} -> {b.sink} "
+                               f"does not join its endpoints")
+        if region is not None:
+            stray = [t for t in tiles if not region.contains(t)]
+            if stray:
+                raise RuntimeError(
+                    f"{nl.name}: route {b.driver} -> {b.sink} left region "
+                    f"{region} at {stray[:3]}")
+        wc = 16 if b.width >= 16 else 1
+        for h in rb.hops:
+            if h.dst not in fabric.neighbors(h.src):
+                raise RuntimeError(f"{nl.name}: hop {h.src} -> {h.dst} of "
+                                   f"{b.driver} -> {b.sink} is not a link")
+            usage.setdefault((h.src, h.dst, wc), set()).add(b.driver)
+    over = [k for k, drivers in usage.items()
+            if len(drivers) > fabric.track_capacity(k[2])]
+    if over:
+        raise RuntimeError(f"{nl.name}: {len(over)} overused boundaries, "
+                           f"e.g. {over[0]}")

@@ -329,10 +329,8 @@ class FabricScheduler:
         except ServiceTimeout:
             self._log(out, cycle, "compile_timeout", app.name)
             return False                # hook already dropped the hold
-        except Exception as e:
-            self._log(out, cycle, "compile_error", app.name,
-                      error=f"{type(e).__name__}: {e}")
-            return False
+        # any other compile fault is a bug (or a device fault), not a
+        # rejection: it propagates to the caller with the hold dropped
         self._holds.pop(app.name, None)
         self._residents[app.name] = Resident(
             app=app, config=cfg, region=slot, result=result, rows=rows,

@@ -16,7 +16,8 @@ Pareto-frontier sweep.  This module removes the per-round Python walk:
   frontier points share one).
 * arrival propagation runs level by level as whole-array gathers
   (numpy) or as one jitted ``lax.scan`` over padded levels (jax, under
-  ``enable_x64`` so float64 arithmetic matches the oracle bit for bit).
+  ``jax.enable_x64`` so float64 arithmetic matches the oracle bit for bit;
+  CPU only, since a TPU's emulated float64 does not).
 * :class:`IncrementalSTA` keeps the arrival vector alive across
   pipelining rounds: a register insertion only flips mask bits, so each
   re-analyze re-propagates just the dirty fanout cone of the edited
@@ -181,12 +182,18 @@ class LoweredSTA:
 
     def propagate_jax(self, mask: np.ndarray) -> np.ndarray:
         import jax
-        from jax.experimental import enable_x64
 
+        if jax.default_backend() != "cpu":
+            # a TPU emulates float64: its max/add do not round like the
+            # oracle's, and the critical path moves in the last bits
+            raise RuntimeError(
+                f"STA backend 'jax' runs only on the CPU: on "
+                f"{jax.default_backend()!r} its float64 arrivals differ "
+                f"from the scalar oracle; use 'numpy'")
         st = self._jax
         if not st:
             st.update(_jax_state(self))
-        with enable_x64():
+        with jax.enable_x64(True):
             arr = st["fn"](st["consts"], jax_mask(mask))
         out = np.asarray(arr, dtype=np.float64)[:self.n_verts]
         return out
@@ -561,8 +568,8 @@ def _jax_state(L: LoweredSTA) -> dict:
     scatter order is irrelevant: every predecessor lives at a strictly
     smaller level, so there are no intra-level dependencies.
     """
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     sent = L.n_verts
     sp_v, sp_p, sp_s, sp_d = [], [], [], []
@@ -591,7 +598,7 @@ def _jax_state(L: LoweredSTA) -> dict:
     w1 = max((len(r) for r in sp_v), default=0)
     w2 = max((len(r) for r in mp_v), default=0)
     w3 = max((len(r) for r in me_d), default=0)
-    with enable_x64():
+    with jax.enable_x64(True):
         consts = (
             jnp.asarray(_pad2(sp_v, w1, sent)), jnp.asarray(_pad2(sp_p, w1, sent)),
             jnp.asarray(_pad2(sp_s, w1, L.n_sites)), jnp.asarray(_pad2f(sp_d, w1)),

@@ -31,10 +31,10 @@ from typing import Dict, List, Sequence, Tuple
 import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeSpec
+from repro.distributed.peaks import TPU_V5E, peaks_for
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+#: stages are planned for v5e pods
+PEAK = peaks_for(TPU_V5E)
 
 
 # ---------------------------------------------------------------------------
@@ -50,8 +50,8 @@ def layer_costs(cfg: ModelConfig, shape: ShapeSpec, chips_per_stage: int,
     fwd_bwd = 3.0 if shape.kind == "train" else 1.0
 
     def t(flops, bytes_):
-        return max(flops / (chips_per_stage * PEAK_FLOPS),
-                   bytes_ / (chips_per_stage * HBM_BW))
+        return max(flops / (chips_per_stage * PEAK.flops),
+                   bytes_ / (chips_per_stage * PEAK.hbm_bw))
 
     out: List[float] = []
     for li in range(cfg.num_layers):
@@ -91,7 +91,7 @@ def boundary_cost(cfg: ModelConfig, shape: ShapeSpec, microbatches: int,
     """Activation transfer time across one stage boundary (per microbatch)."""
     tokens = shape.seq_len * shape.global_batch / microbatches
     act_bytes = tokens * cfg.d_model * 2
-    return act_bytes / (chips_per_stage * ICI_BW)
+    return act_bytes / (chips_per_stage * PEAK.ici_bw)
 
 
 # ---------------------------------------------------------------------------

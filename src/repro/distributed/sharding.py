@@ -96,14 +96,9 @@ def use_rules(rules: Dict[str, PhysAxes]):
 
 
 def _mesh_axis_sizes() -> Dict[str, int]:
-    mesh = getattr(jax.sharding, "get_abstract_mesh", lambda: None)()
-    if mesh is None or not getattr(mesh, "shape", None):
-        env = jax.interpreters.pxla.thread_resources.env
-        mesh = env.physical_mesh
-    try:
-        return dict(mesh.shape)
-    except Exception:
-        return {}
+    """Axis sizes of the mesh made active by ``jax.sharding.set_mesh``
+    (empty when none is)."""
+    return dict(jax.sharding.get_abstract_mesh().shape)
 
 
 def resolve_spec(axes: Axes, rules: Optional[Dict[str, PhysAxes]] = None,
@@ -143,11 +138,10 @@ def resolve_spec(axes: Axes, rules: Optional[Dict[str, PhysAxes]] = None,
 
 def shard(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     """with_sharding_constraint by logical axes (no-op outside a mesh)."""
-    try:
-        spec = resolve_spec(tuple(axes), dims=x.shape)
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    spec = resolve_spec(tuple(axes), dims=x.shape)
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def gather_weight(w: jax.Array) -> jax.Array:
@@ -159,12 +153,10 @@ def gather_weight(w: jax.Array) -> jax.Array:
     constraint's autodiff transpose REDUCE-SCATTERS the weight gradient back
     to the shard, so backward dgrad contracts over an unsharded weight
     (local) instead of emitting [B,S,D]-sized partial-sum all-reduces."""
-    if not get_rules().get("__gather_weights__"):
+    if (not get_rules().get("__gather_weights__")
+            or jax.sharding.get_abstract_mesh().empty):
         return w
-    try:
-        return jax.lax.with_sharding_constraint(w, P(*([None] * w.ndim)))
-    except Exception:
-        return w
+    return jax.lax.with_sharding_constraint(w, P(*([None] * w.ndim)))
 
 
 def specs_for_tree(logical_tree: Any, shapes_tree: Any = None,

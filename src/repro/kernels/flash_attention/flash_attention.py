@@ -20,11 +20,14 @@ the call), keeping the kernel itself single-head-layout.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,7 +85,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                    static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """Attention over q [B, H, S, d] with k, v [B, H, Skv, d].
 
     H must already equal the q-head count (GQA callers repeat K/V heads).
@@ -120,6 +123,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),        # running denominator
             pltpu.VMEM((bq, d), jnp.float32),      # weighted accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, kp, vp)
     return out.reshape(b, h, sqp, d)[:, :, :sq, :]

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +16,7 @@ from .ref import attention_ref
                                              "interpret"))
 def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = True, use_kernel: bool = True,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     """Grouped-query attention: q [B, Hq, S, d], k/v [B, Hkv, Skv, d]."""
     hq, hkv = q.shape[1], k.shape[1]
     if hq % hkv:

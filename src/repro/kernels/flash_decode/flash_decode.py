@@ -10,24 +10,30 @@ row + softmax temporaries back to HBM).
 Layout matches the serving cache ([B, KV, T, hd], the H2 layout-fix
 convention): no transposes.  Grid: (B*KV, T/bk) with the KV-block axis
 innermost/sequential; q for all G group-heads of one kv head rides in VMEM
-across the sweep.  Peak VMEM per step = k + v tiles + q + acc ≈
+across the sweep.  The per-row frontier is a scalar-prefetch operand held in
+SMEM: a (1, 1) VMEM block of a [B*KV, 1] array breaks the TPU's (8, 128)
+block-tiling rule and Mosaic refuses it.  Peak VMEM per step = k + v tiles + q + acc ≈
 2*bk*hd + 2*G*hd floats (~130 KB at bk=256, hd=128, G=8).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                    acc_scr, *, bk: int, scale: float, kv_steps: int):
+    row = pl.program_id(0)
     ki = pl.program_id(1)
 
     @pl.when(ki == 0)
@@ -43,7 +49,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
                             preferred_element_type=jnp.float32) * scale
     # mask cache slots at/after the frontier            [G, bk]
     cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(cols < len_ref[0], s, NEG_INF)
+    s = jnp.where(cols < len_ref[row], s, NEG_INF)
 
     m_prev = m_scr[...]                         # [G]
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -64,7 +70,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
 def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                  lengths: jax.Array, *, bk: int = 256,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """One-token GQA decode attention, cache-layout native.
 
     q:        [B, KV, G, hd]   (new token's query, grouped by kv head)
@@ -84,26 +90,28 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     qf = q.reshape(b * kv, g, hd)
     kf = kp.reshape(b * kv, tp, hd)
     vf = vp.reshape(b * kv, tp, hd)
-    lens = jnp.repeat(lengths.astype(jnp.int32), kv).reshape(b * kv, 1)
+    lens = jnp.repeat(lengths.astype(jnp.int32), kv)            # [B*KV]
 
     kv_steps = tp // bk
     out = pl.pallas_call(
         functools.partial(_decode_kernel, bk=bk, scale=scale,
                           kv_steps=kv_steps),
-        grid=(b * kv, kv_steps),
-        in_specs=[
-            pl.BlockSpec((1, g, hd), lambda i, ki: (i, 0, 0)),
-            pl.BlockSpec((1, bk, hd), lambda i, ki: (i, ki, 0)),
-            pl.BlockSpec((1, bk, hd), lambda i, ki: (i, ki, 0)),
-            pl.BlockSpec((1, 1), lambda i, ki: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, g, hd), lambda i, ki: (i, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * kv, kv_steps),
+            in_specs=[
+                pl.BlockSpec((1, g, hd), lambda i, ki, lens: (i, 0, 0)),
+                pl.BlockSpec((1, bk, hd), lambda i, ki, lens: (i, ki, 0)),
+                pl.BlockSpec((1, bk, hd), lambda i, ki, lens: (i, ki, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, g, hd), lambda i, ki, lens: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((g,), jnp.float32),       # running max
+                pltpu.VMEM((g,), jnp.float32),       # denominator
+                pltpu.VMEM((g, hd), jnp.float32),    # accumulator
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b * kv, g, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),       # running max
-            pltpu.VMEM((g,), jnp.float32),       # denominator
-            pltpu.VMEM((g, hd), jnp.float32),    # accumulator
-        ],
-        interpret=interpret,
-    )(qf, kf, vf, lens)
+        interpret=resolve_interpret(interpret),
+    )(lens, qf, kf, vf)
     return out.reshape(b, kv, g, hd)

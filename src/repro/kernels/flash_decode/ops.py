@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
@@ -12,7 +13,7 @@ from .ref import flash_decode_ref
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
 def decode_attention(q, k_cache, v_cache, lengths, *, use_kernel: bool = True,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     if use_kernel:
         return flash_decode(q, k_cache, v_cache, lengths,
                             interpret=interpret)

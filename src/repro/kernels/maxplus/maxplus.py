@@ -18,10 +18,13 @@ VMEM = bm*bk + bk*bn + bm*bn floats (~192 KB at 128^3) — far under ~16 MB.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e9
 
@@ -48,7 +51,7 @@ def _maxplus_kernel(a_ref, b_ref, o_ref, *, bk: int):
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def maxplus_matmul(a: jax.Array, b: jax.Array, *, bm: int = 128,
                    bn: int = 128, bk: int = 128,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: Optional[bool] = None) -> jax.Array:
     """C[i, j] = max_k (A[i, k] + B[k, j]) over the (max, +) semiring.
 
     Inputs are padded with NEG_INF to block multiples; NEG_INF is the
@@ -75,6 +78,6 @@ def maxplus_matmul(a: jax.Array, b: jax.Array, *, bm: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)
     return out[:m, :n]

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ from .ref import longest_path_ref, maxplus_matmul_ref
 
 @functools.partial(jax.jit, static_argnames=("src", "use_kernel", "interpret"))
 def longest_path(m: jax.Array, src: int = 0, *, use_kernel: bool = True,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """Worst-case arrival time of every vertex from ``src``.
 
     m[i, j] = delay of edge j -> i, NEG_INF when absent.  Runs the max-plus

@@ -17,10 +17,13 @@ The 9-term weighted sum runs on the VPU; peak VMEM is 4 strips —
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _stencil_kernel(x0_ref, x1_ref, x2_ref, w_ref, o_ref, *, width: int):
@@ -36,7 +39,7 @@ def _stencil_kernel(x0_ref, x1_ref, x2_ref, w_ref, o_ref, *, width: int):
 
 @functools.partial(jax.jit, static_argnames=("bh", "interpret"))
 def stencil3x3(x: jax.Array, w: jax.Array, *, bh: int = 128,
-               interpret: bool = True) -> jax.Array:
+               interpret: Optional[bool] = None) -> jax.Array:
     """Same-padded 3x3 correlation of a [H, W] image with a [3, 3] kernel."""
     if x.ndim != 2 or w.shape != (3, 3):
         raise ValueError(f"bad shapes {x.shape}, {w.shape}")
@@ -56,6 +59,6 @@ def stencil3x3(x: jax.Array, w: jax.Array, *, bh: int = 128,
                   pl.BlockSpec((3, 3), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bh, width), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((hp, width), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x0, x1, x2, w)
     return out[:h]

@@ -19,8 +19,8 @@ Per cell this records (experiments/dryrun/<cell>.json):
                              partitioned HLO (all-gather / all-reduce /
                              reduce-scatter / all-to-all / collective-permute)
   * roofline terms         — compute / memory / collective seconds + the
-                             dominant term (TPU v5e: 197 TF/s bf16, 819 GB/s
-                             HBM, ~50 GB/s/link ICI)
+                             dominant term (TPU v5e peaks from
+                             ``repro.distributed.peaks``)
 """
 
 import argparse
@@ -38,16 +38,15 @@ from repro.configs import (ARCHS, SHAPES, cell_is_runnable, get_config,
                            model_flops)
 from repro.data.pipeline import batch_specs
 from repro.distributed import sharding as shd
+from repro.distributed.peaks import TPU_V5E, peaks_for
 from repro.launch import steps as S
 from repro.launch.mesh import make_production_mesh
 from repro.models import LM
 
 # ---------------------------------------------------------------------------
-# hardware constants (TPU v5e)
+# the production mesh is a v5e pod: its rooflines use v5e peaks
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link
+PEAK = peaks_for(TPU_V5E)
 
 _DTYPE_BYTES = {"f64": 8, "s64": 8, "u64": 8, "c64": 8, "f32": 4, "s32": 4,
                 "u32": 4, "bf16": 2, "f16": 2, "s16": 2, "u16": 2,
@@ -163,9 +162,9 @@ def _cost_dict(compiled) -> Dict[str, float]:
 
 def roofline_terms(flops: float, hbm_bytes: float, coll_bytes: float
                    ) -> Dict[str, Any]:
-    t_c = flops / PEAK_FLOPS
-    t_m = hbm_bytes / HBM_BW
-    t_x = coll_bytes / ICI_BW
+    t_c = flops / PEAK.flops
+    t_m = hbm_bytes / PEAK.hbm_bw
+    t_x = coll_bytes / PEAK.ici_bw
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_x),
               key=lambda kv: kv[1])
     return {"compute_s": t_c, "memory_s": t_m, "collective_s": t_x,
@@ -181,13 +180,14 @@ def build_cell(cfg, shape, multi_pod: bool):
     """Returns (mesh, jitted fn, SDS args) for the cell.
 
     NOTE: sharding specs are resolved against the ACTIVE mesh (axis
-    presence + divisibility checks), so everything is built inside
-    ``with mesh:`` — resolving outside would silently replicate."""
+    presence + divisibility checks), so everything is built under
+    ``jax.sharding.set_mesh(mesh)`` — resolving outside would silently
+    replicate."""
     model = LM(cfg)
     mesh = make_production_mesh(multi_pod=multi_pod)
     shd.set_rules(S.rules_for(cfg))
 
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         repl = NamedSharding(mesh, P())
 
         def logits_sh(batch, vocab):
@@ -231,7 +231,7 @@ def build_cell(cfg, shape, multi_pod: bool):
 def _lower_compile(cfg, shape, multi_pod):
     mesh, jitted, args = build_cell(cfg, shape, multi_pod)
     t0 = time.time()
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         lowered = jitted.lower(*args)
     t_lower = time.time() - t0
     t0 = time.time()
@@ -246,7 +246,7 @@ def exact_arg_bytes(cfg, shape, multi_pod) -> int:
     model = LM(cfg)
     mesh = make_production_mesh(multi_pod=multi_pod)
     shd.set_rules(S.rules_for(cfg))
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         if shape.kind == "train":
             opt_cfg = S.make_optimizer_config(cfg)
             shardings, b_sh = S.train_shardings(model, opt_cfg, mesh, shape)
@@ -410,14 +410,14 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
         hbm_corr = max(0.0, hbm - corr["subtract"]) + corr["add"]
         coll = pr.get("collective_bytes_per_device", 0.0)
         cell["roofline"] = roofline_terms(flops, hbm_corr, coll)
-        cell["roofline"]["memory_s_uncorrected"] = hbm / HBM_BW
+        cell["roofline"]["memory_s_uncorrected"] = hbm / PEAK.hbm_bw
         mf = model_flops(cfg, shape)
         cell["model_flops_total"] = mf
         cell["model_flops_per_device"] = mf / n_dev
         if flops:
             cell["useful_flop_ratio"] = round(mf / n_dev / flops, 4)
             cell["roofline_fraction"] = round(
-                (mf / n_dev / PEAK_FLOPS) /
+                (mf / n_dev / PEAK.flops) /
                 cell["roofline"]["step_time_lower_bound_s"], 4)
     return _emit(cell, out_dir)
 

@@ -20,24 +20,35 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes, devices=None):
+    # the model resolves logical axes to PartitionSpecs and leaves layout
+    # propagation to XLA, which needs Auto axes (make_mesh defaults to
+    # Explicit, under which contracting over a sharded dim is an error)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_smoke_mesh():
     """1-device mesh with the production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_mesh_for(devices: Optional[int] = None, model_parallel: int = 16):
-    """Elastic variant: build a (data, model) mesh over `devices` chips
-    (defaults to whatever is visible) — used by the elastic-rescale path."""
-    n = devices or len(jax.devices())
+    """(data, model) mesh over the first `devices` visible chips (default:
+    all of them), tensor parallel over up to `model_parallel` — what the
+    serve and train launchers run on."""
+    devs = jax.devices()[:devices] if devices else jax.devices()
+    n = len(devs)
     mp = min(model_parallel, n)
     while n % mp:
         mp -= 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return _auto_mesh((n // mp, mp), ("data", "model"), devices=devs)

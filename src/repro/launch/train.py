@@ -4,10 +4,12 @@
         --shape train_4k --steps 100 [--smoke] [--ckpt-dir /path] \
         [--fail-at 30,60] [--resume]
 
-On a real TPU slice this script runs unmodified with the production mesh;
-``--smoke`` shrinks the model to its reduced family config and uses the
-1-device mesh so the identical control flow (mesh -> shardings -> jit ->
-fault-tolerant loop -> checkpoints) is exercised on CPU.
+The mesh spans every visible device (``make_mesh_for``), so the same
+command trains on one chip, a four-chip host or a pod slice;
+``--multi-pod`` builds the 512-chip (pod, data, model) production mesh.
+``--smoke`` shrinks the model to its reduced family config so the identical
+control flow (mesh -> shardings -> jit -> fault-tolerant loop ->
+checkpoints) is exercised on CPU.
 """
 
 from __future__ import annotations
@@ -25,7 +27,8 @@ from repro.configs import ARCHS, SHAPES, get_config
 from repro.data.pipeline import SyntheticLMData
 from repro.distributed import sharding as shd
 from repro.launch import steps as S
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh
+from repro.launch.jax_cache import use_compile_cache
+from repro.launch.mesh import make_mesh_for, make_production_mesh
 from repro.models import LM
 from repro.runtime import FailureInjector, FaultTolerantLoop, StragglerPolicy
 
@@ -36,7 +39,7 @@ def main():
     ap.add_argument("--shape", choices=sorted(SHAPES), default="train_4k")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--smoke", action="store_true",
-                    help="reduced config + 1-device mesh (CPU)")
+                    help="the family's reduced config (CPU)")
     ap.add_argument("--batch", type=int, default=0,
                     help="override global batch (smoke default 4)")
     ap.add_argument("--seq", type=int, default=0,
@@ -50,26 +53,25 @@ def main():
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     shape = SHAPES[args.shape]
+    mesh = (make_production_mesh(multi_pod=True) if args.multi_pod
+            else make_mesh_for())
     if args.smoke:
         cfg = cfg.smoke()
-        mesh = make_smoke_mesh()
         shape = shape.__class__(shape.name, args.seq or 128,
                                 args.batch or 4, shape.kind)
-    else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
-        if args.batch or args.seq:
-            shape = shape.__class__(shape.name, args.seq or shape.seq_len,
-                                    args.batch or shape.global_batch,
-                                    shape.kind)
+    elif args.batch or args.seq:
+        shape = shape.__class__(shape.name, args.seq or shape.seq_len,
+                                args.batch or shape.global_batch, shape.kind)
 
     model = LM(cfg)
     opt_cfg = S.make_optimizer_config(cfg, total_steps=args.steps)
     shd.set_rules(S.rules_for(cfg))
     data = SyntheticLMData(cfg, shape)
 
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         st_sh, b_sh = S.train_shardings(model, opt_cfg, mesh, shape)
         step_fn = jax.jit(S.make_train_step(model, opt_cfg),
                           in_shardings=(st_sh, b_sh),

@@ -27,7 +27,7 @@ from repro.runtime.fault_tolerance import InjectedFailure
 
 def test_resolve_spec_divisibility_fallback():
     mesh = make_smoke_mesh()
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         # "model" axis size 1 always divides; 17 % 1 == 0 -> kept
         spec = shd.resolve_spec(("embed", "vocab"), dims=(17, 32))
         assert isinstance(spec, P)
@@ -35,7 +35,7 @@ def test_resolve_spec_divisibility_fallback():
 
 def test_resolve_spec_drops_missing_axes():
     mesh = make_smoke_mesh()     # no "pod" axis
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         spec = shd.resolve_spec(("batch", "seq"), dims=(8, 16))
         flat = []
         for entry in spec:
@@ -49,7 +49,7 @@ def test_resolve_spec_drops_missing_axes():
 def test_resolve_spec_never_reuses_axis():
     mesh = make_smoke_mesh()
     rules = shd.rules_with(embed="model", mlp="model")
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         spec = shd.resolve_spec(("embed", "mlp"), rules=rules, dims=(16, 16))
         used = [a for a in jax.tree.leaves(tuple(spec)) if a]
         assert len(used) == len(set(used))
@@ -75,7 +75,7 @@ def test_train_step_on_smoke_mesh():
     mesh = make_smoke_mesh()
     shape = SHAPES["train_4k"]
     shd.set_rules(S.rules_for(cfg))
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         st_sh, b_sh = S.train_shardings(model, opt_cfg, mesh, shape)
         step = jax.jit(S.make_train_step(model, opt_cfg),
                        in_shardings=(st_sh, b_sh),

@@ -1,6 +1,8 @@
 """Launch-layer units: roofline math, collective parsing, probe configs,
 cell bookkeeping, pipeline partitioning properties."""
 
+import os
+
 import jax
 import numpy as np
 import pytest
@@ -129,3 +131,28 @@ def test_layer_costs_reflect_heterogeneity():
     shared = [costs[i] for i in range(5, 54, 6)]
     plain = [costs[i] for i in range(54) if (i + 1) % 6]
     assert min(shared) > max(plain)
+
+
+def test_peaks_are_keyed_by_device_kind():
+    from repro.distributed.peaks import TPU_V5E, peaks_for
+    assert peaks_for(TPU_V5E).flops == 197e12
+    with pytest.raises(KeyError, match="cpu"):
+        peaks_for("cpu")
+
+
+def test_compile_cache_honours_env_and_else_uses_checkout_dir(monkeypatch):
+    import jax
+    from repro.launch import jax_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert jax_cache.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = jax_cache.use_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

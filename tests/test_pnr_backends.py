@@ -177,6 +177,24 @@ def test_jax_route_region_fenced():
     assert_legal_routes(rd, pl, FABRIC, region=region)
 
 
+def test_check_legal_rejects_broken_routes():
+    """The legality backstop every route() runs: a route that stops short
+    of its sink, or one that leaves its region, is refused."""
+    import copy
+    from repro.core.route import check_legal
+    nl = _netlist("vecadd")
+    pl = place(nl, FABRIC, PlaceParams(seed=2, moves_per_node=60))
+    design = route(nl, pl, FABRIC)
+    check_legal(design)
+    key = next(k for k, rb in design.routes.items() if len(rb.hops) > 1)
+    short = copy.deepcopy(design)
+    short.routes[key].hops.pop()
+    with pytest.raises(RuntimeError, match="does not join its endpoints"):
+        check_legal(short)
+    with pytest.raises(RuntimeError, match="left region"):
+        check_legal(design, region=Region(0, 0, 1, 1))
+
+
 def test_unknown_backend_rejected():
     with pytest.raises(ValueError, match="unknown place backend"):
         place(_netlist(), FABRIC, PlaceParams(backend="torch"))

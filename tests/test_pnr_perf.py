@@ -138,6 +138,26 @@ def test_auto_backend_picks_process_only_for_multi_miss_batches():
     assert c.last_batch["cache_hits"] == 2 and c.last_batch["compiled"] == 0
 
 
+def test_jax_backend_jobs_compile_in_process(monkeypatch):
+    """Only one process may hold the accelerator, so jax-kernel jobs never
+    go to a process pool, even when more than one misses."""
+    import repro.core.compiler as compiler_mod
+
+    def no_pool(*a, **kw):
+        raise AssertionError("a jax-backend job reached a process pool")
+
+    monkeypatch.setattr(compiler_mod, "ProcessPoolExecutor", no_pool)
+    c = CascadeCompiler(fabric=Fabric(rows=8, cols=8, mem_col_stride=4),
+                        cache=CompileCache())
+    app = ALL_APPS["vecadd"]
+    jobs = [(app, PassConfig.full(place_moves=20, seed=s, pnr_backend="jax",
+                                  pnr_replicas=2)) for s in (1, 2)]
+    out = c.compile_batch(jobs, backend="auto")
+    assert c.last_batch["backend"] == "thread"
+    assert c.last_batch["compiled"] == 2
+    assert all(r.sta.critical_path_ns > 0 for r in out)
+
+
 def test_process_backend_unpicklable_job_falls_back_inline():
     app = ALL_APPS["vecadd"]
     # a closure builder cannot cross the process boundary

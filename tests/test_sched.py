@@ -199,6 +199,27 @@ def test_scheduler_rejects_unknown_apps_and_policies():
         FabricScheduler(service=make_service(NARROW), policy="greedy")
 
 
+class FaultyCompiler(CascadeCompiler):
+    def compile(self, *a, **kw):
+        raise RuntimeError("device fault")
+
+
+def test_compile_fault_surfaces_instead_of_rejecting():
+    """A compile that fails for any reason but a timeout is a fault, not
+    an admission decision: the run raises rather than logging and
+    carrying on."""
+    trace = session_trace([("vecadd", 0, None)], period=1000)
+    svc = CompileService(compiler=FaultyCompiler(fabric=NARROW),
+                         batch_window_s=0.0).start()
+    try:
+        sched = FabricScheduler(service=svc)
+        with pytest.raises(RuntimeError, match="device fault"):
+            sched.run(trace, ALL_APPS, configs=configs(trace.arrivals))
+        assert not sched._holds
+    finally:
+        svc.stop()
+
+
 # ---------------------------------------------------------------------------
 # randomized long-trace soak (slow lane)
 # ---------------------------------------------------------------------------

@@ -128,6 +128,19 @@ def test_unknown_vec_backend_rejected():
         IncrementalSTA(design, tm, backend="torch")
 
 
+def test_jax_backend_refused_off_the_cpu(monkeypatch):
+    """A TPU emulates float64 and its arrivals differ from the oracle's in
+    the last bits: the jax STA refuses to run there rather than report a
+    different critical path."""
+    import jax
+    design, tm = _routed("gaussian", 1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="runs only on the CPU"):
+        analyze(design, tm, backend="jax")
+    with pytest.raises(RuntimeError, match="runs only on the CPU"):
+        IncrementalSTA(design, tm, backend="jax").analyze()
+
+
 # ---------------------------------------------------------------------------
 # randomized register states (property suite)
 # ---------------------------------------------------------------------------

@@ -62,7 +62,7 @@ def test_train_loop_with_failure_recovers_and_descends(tmp_path):
     data = SyntheticLMData(cfg, shape)
     mgr = CheckpointManager(str(tmp_path / "ck"), keep=2, async_save=False)
     losses = []
-    with mesh:
+    with jax.sharding.set_mesh(mesh):
         st_sh, b_sh = S.train_shardings(model, opt_cfg, mesh, shape)
         step = jax.jit(S.make_train_step(model, opt_cfg),
                        in_shardings=(st_sh, b_sh),
@@ -97,7 +97,7 @@ def test_serve_path_generates():
     cfg = get_config("llama3-8b").smoke()
     model = LM(cfg)
     shd.set_rules(S.rules_for(cfg))
-    with make_smoke_mesh():
+    with jax.sharding.set_mesh(make_smoke_mesh()):
         params = model.init(jax.random.PRNGKey(0))
         cache = model.init_cache(2, 24)
         toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,

@@ -1,0 +1,111 @@
+"""Compile the main path's device programs for one TPU v5e chip, without a
+chip: the TPU compiler refuses what interpret mode and the CPU backend
+accept (misaligned Pallas blocks, too much VMEM, unsupported ops).
+
+Every program is captured at the shapes a real ``harris`` x4 compile (and
+a granite-moe-1b-a400m serve) produces here on the CPU, then lowered and
+compiled for a described ``v5e:2x2`` chip.  Nothing runs on the TPU.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import ALL_APPS, CascadeCompiler, CompileCache, PassConfig
+from repro.core import place_jax, route_jax, sim_vec, sta_vec
+from repro.core.sim import simulate
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # entries written for a described chip cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def harris_programs():
+    """(jitted fn, call args) of each jax kernel a harris x4 compile and
+    simulation run, recorded from a CPU run at the same shapes."""
+    captured = {}
+
+    def recording(name, factory):
+        def make(*static):
+            fn = factory(*static)
+
+            def call(*args):
+                captured.setdefault(name, (fn, args))
+                return fn(*args)
+            return call
+        return make
+
+    mp = pytest.MonkeyPatch()
+    for mod, attr, name in ((place_jax, "_jitted_anneal", "place"),
+                            (route_jax, "_jitted_router", "route"),
+                            (sta_vec, "_jitted_propagate", "sta"),
+                            (sim_vec, "_jitted_dense", "sim")):
+        mp.setattr(mod, attr, recording(name, getattr(mod, attr)))
+    try:
+        res = CascadeCompiler(cache=CompileCache(),
+                              stage_cache=CompileCache()).compile(
+            ALL_APPS["harris"],
+            PassConfig.full(pnr_backend="jax", sta_backend="jax"))
+        g = res.design.netlist.to_dfg()
+        ins = {n: [1] * 16 for n, nd in g.nodes.items() if nd.kind == "input"}
+        simulate(g, ins, 16_384, backend="jax")
+    finally:
+        mp.undo()
+    return captured
+
+
+def _compile_for(chip, fn, args):
+    sds = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), args)
+    return fn.lower(*sds).compile()
+
+
+@pytest.mark.parametrize("kernel", ["place", "route", "sim"])
+def test_harris_kernel_compiles_for_v5e(one_chip, harris_programs, kernel):
+    fn, args = harris_programs[kernel]
+    assert _compile_for(one_chip, fn, args) is not None
+
+
+def test_harris_sta_compiles_for_v5e(one_chip, harris_programs):
+    fn, args = harris_programs["sta"]
+    with jax.enable_x64(True):           # the propagation carries float64
+        compiled = _compile_for(one_chip, fn, args)
+    assert compiled is not None
+
+
+def test_flash_attention_compiles_for_v5e_at_granite_widths(one_chip):
+    from repro.kernels.flash_attention import flash_attention
+    q = jax.ShapeDtypeStruct((1, 16, 2048, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = flash_attention.lower(q, q, q, causal=True,
+                                     interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_decode_compiles_for_v5e_at_granite_widths(one_chip):
+    from repro.kernels.flash_decode import flash_decode
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q = sds((4, 8, 2, 64), jnp.bfloat16)
+    cache = sds((4, 8, 2048, 64), jnp.bfloat16)
+    lengths = sds((4,), jnp.int32)
+    compiled = flash_decode.lower(q, cache, cache, lengths,
+                                  interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
