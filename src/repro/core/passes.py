@@ -28,10 +28,10 @@ mechanism behind the in-compile design-space exploration of
 from __future__ import annotations
 
 import copy
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
+from ..runtime.spans import span
 from .apps import AppSpec
 from .branch_delay import check_matched_netlist, check_predicated_regions
 from .broadcast import broadcast_pipelining
@@ -428,9 +428,9 @@ class PassPipeline:
         for idx in range(start, stop):
             p = self.passes[idx]
             if p.enabled(ctx):
-                t0 = time.perf_counter()
-                stats = p.run(ctx)
-                ctx.pass_times[p.name] = time.perf_counter() - t0
+                with span(f"cascade.pass.{p.name}") as s:
+                    stats = p.run(ctx)
+                ctx.pass_times[p.name] = s.seconds
                 ctx.executed.append(p.name)
                 if stats is not None and p.stats_key is not None:
                     ctx.pass_stats[p.stats_key] = stats
@@ -574,14 +574,15 @@ def _run_route(ctx: CompileContext):
     ``inf`` — a resident's nets can never borrow a neighbour's tracks."""
     ctx.require(netlist=ctx.netlist, placement=ctx.placement,
                 place_fabric=ctx.place_fabric)
+    route_stats: dict = {}
     design = route(ctx.netlist, ctx.placement, ctx.place_fabric,
                    RouteParams(backend=ctx.config.pnr_backend),
-                   region=ctx.config.region)
+                   region=ctx.config.region, stats=route_stats)
     design.unroll_copies = ctx.copies
     design.source_dfg = ctx.source_dfg
     ctx.design = design
     return {"wirelength": design.total_wirelength(),
-            "routes": len(design.routes)}
+            "routes": len(design.routes), **route_stats}
 
 
 #: ``place`` keeps the historical ``"pnr"`` stats bucket (its dict carries
@@ -638,7 +639,8 @@ def _post_pnr(ctx: CompileContext):
                             sta_backend=ctx.config.sta_backend)
     ctx.post_pnr = ppr
     return {"initial_ns": ppr.initial_ns, "final_ns": ppr.final_ns,
-            "registers_added": ppr.registers_added, "stop": ppr.stop_reason}
+            "registers_added": ppr.registers_added,
+            "iterations": ppr.iterations, "stop": ppr.stop_reason}
 
 
 @register_pass("power_capped_pipeline", stats_key="power_cap",

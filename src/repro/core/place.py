@@ -34,7 +34,6 @@ several banks per array column).
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -160,7 +159,8 @@ def place(nl: Netlist, fabric: Fabric,
     """Anneal a placement; returns node -> tile.
 
     ``stats`` (optional dict) is filled with kernel counters: mode, move /
-    acceptance counts, resyncs, and wall-clock seconds.
+    acceptance counts, resyncs and the best cost; the ``place`` pass
+    times the whole call.
 
     ``region`` (multi-app fabric sharing) restricts the placement to a
     rectangular window the application owns: the site pools — and therefore
@@ -173,7 +173,6 @@ def place(nl: Netlist, fabric: Fabric,
     backend = p.resolved_backend()
     vectorized = backend != "scalar"
     debug = place_debug() if p.debug is None else p.debug
-    t_start = time.perf_counter()
     rng = np.random.default_rng(p.seed)
     nets = _Nets(nl)
     n = len(nets.names)
@@ -340,7 +339,6 @@ def place(nl: Netlist, fabric: Fabric,
             "moves_accepted": moves_accepted,
             "resyncs": resyncs,
             "best_cost": float(best_cost),
-            "place_seconds": time.perf_counter() - t_start,
         })
         if region is not None:
             stats["region"] = (region.row0, region.col0,

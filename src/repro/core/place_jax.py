@@ -51,6 +51,8 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
+from ..runtime.spans import span
+
 # class order defines the flattened site-slot space: [pe | mem | io]
 _CLASS_ORDER = ("pe", "mem", "io")
 
@@ -303,13 +305,6 @@ def anneal_jax(nets, cls: List[str], sites: Dict[str, list], p,
     from jax import random
 
     n = len(cls)
-    n_nets = len(nets.nets)
-    site_rc, class_off, class_pool = _flatten_sites(sites)
-    node_off = np.asarray([class_off[c] for c in cls], dtype=np.int32)
-    node_pool = np.asarray([class_pool[c] for c in cls], dtype=np.int32)
-    node_nets_mat = _padded_node_nets(nets, n)
-    n_slots = len(site_rc)
-
     devs = jax.devices()
     # size-adaptive ensemble policy: small netlists are cheap to anneal
     # but their single-chain cost is high-variance, so they get more,
@@ -327,78 +322,89 @@ def anneal_jax(nets, cls: List[str], sites: Dict[str, list], p,
         replicas += len(devs) - replicas % len(devs)
     K = max(1, int(p.proposal_block))
 
-    # --- per-replica initial states (seed-derived, replica-salted) -------
-    site0 = np.zeros((replicas, n), dtype=np.int32)
-    occ0 = np.full((replicas, n_slots), -1, dtype=np.int32)
-    for r in range(replicas):
-        rs = np.random.default_rng([int(p.seed), r])
-        for c in _CLASS_ORDER:
-            members = [i for i in range(n) if cls[i] == c]
-            if not members:
-                continue
-            chosen = rs.choice(class_pool[c], size=len(members),
-                               replace=False)
-            for i, k in zip(members, chosen):
-                s = class_off[c] + int(k)
-                site0[r, i] = s
-                occ0[r, s] = i
+    with span("cascade.place.setup", replicas=replicas, nodes=n, K=K):
+        n_nets = len(nets.nets)
+        site_rc, class_off, class_pool = _flatten_sites(sites)
+        node_off = np.asarray([class_off[c] for c in cls], dtype=np.int32)
+        node_pool = np.asarray([class_pool[c] for c in cls], dtype=np.int32)
+        node_nets_mat = _padded_node_nets(nets, n)
+        n_slots = len(site_rc)
 
-    from .place import _net_cost_batch
-    pos0 = site_rc[site0[0]].astype(np.int64)
-    cost0 = np.asarray([
-        _net_cost_batch(site_rc[site0[r]].astype(np.int64), nets.term_mat,
-                        nets.term_count, p.gamma, p.alpha).sum()
-        for r in range(replicas)], dtype=np.float32)
+        # --- per-replica initial states (seed-derived, replica-salted) ---
+        site0 = np.zeros((replicas, n), dtype=np.int32)
+        occ0 = np.full((replicas, n_slots), -1, dtype=np.int32)
+        for r in range(replicas):
+            rs = np.random.default_rng([int(p.seed), r])
+            for c in _CLASS_ORDER:
+                members = [i for i in range(n) if cls[i] == c]
+                if not members:
+                    continue
+                chosen = rs.choice(class_pool[c], size=len(members),
+                                   replace=False)
+                for i, k in zip(members, chosen):
+                    s = class_off[c] + int(k)
+                    site0[r, i] = s
+                    occ0[r, s] = i
 
-    base_temp = _probe_temperature(
-        nets, pos0, node_off, node_pool, site_rc,
-        p.gamma, p.alpha, np.random.default_rng(p.seed))
-    # geometric ladder: slot 0 anneals the NumPy schedule, higher slots
-    # run hotter so exchanges can tunnel out of local minima
-    temps0 = base_temp * (spread ** np.arange(replicas))
+        from .place import _net_cost_batch
+        pos0 = site_rc[site0[0]].astype(np.int64)
+        cost0 = np.asarray([
+            _net_cost_batch(site_rc[site0[r]].astype(np.int64), nets.term_mat,
+                            nets.term_count, p.gamma, p.alpha).sum()
+            for r in range(replicas)], dtype=np.float32)
 
-    # every replica evaluates the full NumPy move budget; the speedup
-    # comes from evaluating K proposals per sequential step, not from
-    # shortening the anneal
-    total_moves = budget_boost * p.moves_per_node * max(n, 16)
-    n_temps = max(1, int(math.log(5e-4) / math.log(p.t_factor)))
-    blocks_per_temp = max(1, total_moves // n_temps // K)
+        base_temp = _probe_temperature(
+            nets, pos0, node_off, node_pool, site_rc,
+            p.gamma, p.alpha, np.random.default_rng(p.seed))
+        # geometric ladder: slot 0 anneals the NumPy schedule, higher slots
+        # run hotter so exchanges can tunnel out of local minima
+        temps0 = base_temp * (spread ** np.arange(replicas))
 
-    hmax = int(site_rc[:, 0].max() - site_rc[:, 0].min())
-    wmax = int(site_rc[:, 1].max() - site_rc[:, 1].min())
-    pow_tab = np.power(
-        np.arange(hmax + wmax + 1, dtype=np.float64)[:, None]
-        + p.gamma * np.arange((hmax + 1) * (wmax + 1) + 1,
-                              dtype=np.float64)[None, :],
-        p.alpha).astype(np.float32)
-    tables = (jnp.asarray(site_rc), jnp.asarray(node_off),
-              jnp.asarray(node_pool), jnp.asarray(node_nets_mat),
-              jnp.asarray(nets.term_mat.astype(np.int32)),
-              jnp.asarray(nets.term_count.astype(np.int32)),
-              jnp.asarray(pow_tab))
-    state = (jnp.asarray(site0), jnp.asarray(occ0), jnp.asarray(cost0),
-             jnp.asarray(cost0),                     # best_cost
-             jnp.asarray(site0),                     # best_site
-             jnp.zeros(replicas, dtype=jnp.int32),   # evaluated
-             jnp.zeros(replicas, dtype=jnp.int32))   # accepted
-    temps = jnp.asarray(temps0.astype(np.float32))
-    if len(devs) > 1:
-        # shard the replica axis across the host mesh (the tables are
-        # replicated by XLA)
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        mesh = Mesh(np.asarray(devs), ("r",))
-        state = tuple(
-            jax.device_put(x, NamedSharding(
-                mesh, P("r", *([None] * (x.ndim - 1)))))
-            for x in state)
+        # every replica evaluates the full NumPy move budget; the speedup
+        # comes from evaluating K proposals per sequential step, not from
+        # shortening the anneal
+        total_moves = budget_boost * p.moves_per_node * max(n, 16)
+        n_temps = max(1, int(math.log(5e-4) / math.log(p.t_factor)))
+        blocks_per_temp = max(1, total_moves // n_temps // K)
 
-    anneal = _jitted_anneal(n, n_nets, n_slots, replicas, K,
-                            n_temps, blocks_per_temp)
-    out = anneal(tables, state, temps, random.PRNGKey(int(p.seed)),
-                 jnp.float32(p.t_factor))
-    best_costs = np.asarray(out[3], dtype=np.float64)
-    best_r = int(best_costs.argmin())
-    best_pos = site_rc[np.asarray(out[4][best_r])].astype(np.int64)
+        hmax = int(site_rc[:, 0].max() - site_rc[:, 0].min())
+        wmax = int(site_rc[:, 1].max() - site_rc[:, 1].min())
+        pow_tab = np.power(
+            np.arange(hmax + wmax + 1, dtype=np.float64)[:, None]
+            + p.gamma * np.arange((hmax + 1) * (wmax + 1) + 1,
+                                  dtype=np.float64)[None, :],
+            p.alpha).astype(np.float32)
+        tables = (jnp.asarray(site_rc), jnp.asarray(node_off),
+                  jnp.asarray(node_pool), jnp.asarray(node_nets_mat),
+                  jnp.asarray(nets.term_mat.astype(np.int32)),
+                  jnp.asarray(nets.term_count.astype(np.int32)),
+                  jnp.asarray(pow_tab))
+        state = (jnp.asarray(site0), jnp.asarray(occ0), jnp.asarray(cost0),
+                 jnp.asarray(cost0),                     # best_cost
+                 jnp.asarray(site0),                     # best_site
+                 jnp.zeros(replicas, dtype=jnp.int32),   # evaluated
+                 jnp.zeros(replicas, dtype=jnp.int32))   # accepted
+        temps = jnp.asarray(temps0.astype(np.float32))
+        if len(devs) > 1:
+            # shard the replica axis across the host mesh (the tables are
+            # replicated by XLA)
+            from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+            mesh = Mesh(np.asarray(devs), ("r",))
+            state = tuple(
+                jax.device_put(x, NamedSharding(
+                    mesh, P("r", *([None] * (x.ndim - 1)))))
+                for x in state)
+
+    with span("cascade.place.anneal", replicas=replicas, nodes=n, K=K):
+        anneal = _jitted_anneal(n, n_nets, n_slots, replicas, K,
+                                n_temps, blocks_per_temp)
+        out = anneal(tables, state, temps, random.PRNGKey(int(p.seed)),
+                     jnp.float32(p.t_factor))
+        best_costs = np.asarray(out[3], dtype=np.float64)
+        best_r = int(best_costs.argmin())
+        best_pos = site_rc[np.asarray(out[4][best_r])].astype(np.int64)
+        evaluated = int(np.asarray(out[5]).sum())
+        accepted = int(np.asarray(out[6]).sum())
 
     # re-derive the winning cost in float64 through the NumPy Eq. 1 kernel
     # so cross-backend cost comparisons are apples to apples
@@ -409,8 +415,8 @@ def anneal_jax(nets, cls: List[str], sites: Dict[str, list], p,
         "replicas": replicas,
         "devices": len(devs),
         "proposal_block": K,
-        "moves_evaluated": int(np.asarray(out[5]).sum()),
-        "moves_accepted": int(np.asarray(out[6]).sum()),
+        "moves_evaluated": evaluated,
+        "moves_accepted": accepted,
         "resyncs": int(n_temps * blocks_per_temp),
         "best_replica": best_r,
         "replica_costs": [round(float(c), 3) for c in best_costs],

@@ -23,6 +23,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
+from ..runtime.spans import span
 from .branch_delay import MatchPlan
 from .netlist import RoutedDesign
 from .sta import STAReport, analyze
@@ -218,6 +219,12 @@ def _make_engine(design: RoutedDesign, tm: TimingModel, sta_backend: str,
     return IncrementalSTA(design, tm, backend=sta_backend, lowering=lowering)
 
 
+def _analyze(engine) -> STAReport:
+    """One timing run of the loop, as a ``cascade.sta`` span."""
+    with span("cascade.sta"):
+        return engine.analyze()
+
+
 def post_pnr_pipeline(design: RoutedDesign, tm: TimingModel,
                       params: Optional[PostPnRParams] = None,
                       round_hook: Optional[RoundHook] = None,
@@ -234,81 +241,89 @@ def post_pnr_pipeline(design: RoutedDesign, tm: TimingModel,
     and stop reasons — one shared loop drives an engine seam, so the
     control flow cannot drift, and the engines' reports are bit-identical
     by construction (asserted in tests and benchmarks).
+
+    Each round is a ``cascade.post_pnr.round`` span (its number and the
+    registers added before it) and each timing run a ``cascade.sta`` span.
     """
     p = params or PostPnRParams()
     engine = _make_engine(design, tm, sta_backend, lowering)
     # branch topology is frozen during the loop; precompute the match
     # structure once instead of re-toposorting the netlist every round
     match_plan = MatchPlan(design.netlist)
-    rep = engine.analyze()
+    rep = _analyze(engine)
     initial = rep.critical_path_ns
     history = [initial]
     stall = 0
     reason = "max_iters"
 
     for it in range(p.max_iters):
-        if p.target_ns and rep.critical_path_ns <= p.target_ns:
-            reason = "target_reached"
-            break
-        cands = engine.segment_candidates(rep)
-        if not cands:
-            reason = "core_bound"  # segment has no free register site
-            break
-        # pick the site closest to the segment's delay midpoint
-        total = rep.critical_path_ns - tm.sequential_overhead()
-        bkey, hop_idx, _ = min(cands, key=lambda c: abs(c[2] - total / 2.0))
-
-        delta = _RoundDelta.capture(design)        # for in-loop revert
-
-        rb = design.routes[bkey]
-        rb.reg_hops.add(hop_idx)
-        delta.added.append((bkey, hop_idx))
-        rb.branch.n_regs += 1
-        added = 1 + match_plan.run()
-        # materialize matching registers on routes (keep manually placed sites)
-        for key2, rb2 in design.routes.items():
-            want = rb2.branch.n_regs
-            have = len(rb2.reg_hops)
-            if have < want:
-                for idx in _add_regs_balanced(rb2, want - have):
-                    delta.added.append((key2, idx))
-        engine.notify_added(delta.added)
-
-        if p.register_budget is not None and \
-                design.netlist.added_registers() > p.register_budget:
-            delta.undo(design)
-            engine.notify_removed(delta.added)
-            reason = "register_budget"
-            break
-
-        new_rep = engine.analyze()
-        reverted = False
-        if new_rep.critical_path_ns > rep.critical_path_ns:
-            delta.undo(design)
-            engine.notify_removed(delta.added)
-            new_rep = rep
-            reverted = True
-        # budget hook: consulted on every round that changed the design,
-        # *before* the convergence check — a no-improvement round still
-        # spends a register and must not slip past an external budget
-        if round_hook is not None and not reverted \
-                and not round_hook(design, new_rep):
-            engine.resync()              # the hook may have rewound the design
-            rep = engine.analyze()
-            history.append(rep.critical_path_ns)
-            reason = "round_hook"
-            break
-        if new_rep.critical_path_ns >= rep.critical_path_ns - p.min_improvement:
-            stall += 1
-            if stall >= p.patience:
-                rep = new_rep
-                history.append(rep.critical_path_ns)
-                reason = "converged"
+        with span("cascade.post_pnr.round", round=it,
+                  registers=design.netlist.added_registers()):
+            if p.target_ns and rep.critical_path_ns <= p.target_ns:
+                reason = "target_reached"
                 break
-        else:
-            stall = 0
-        rep = new_rep
-        history.append(rep.critical_path_ns)
+            cands = engine.segment_candidates(rep)
+            if not cands:
+                reason = "core_bound"  # segment has no free register site
+                break
+            # pick the site closest to the segment's delay midpoint
+            total = rep.critical_path_ns - tm.sequential_overhead()
+            bkey, hop_idx, _ = min(cands,
+                                   key=lambda c: abs(c[2] - total / 2.0))
+
+            delta = _RoundDelta.capture(design)        # for in-loop revert
+
+            rb = design.routes[bkey]
+            rb.reg_hops.add(hop_idx)
+            delta.added.append((bkey, hop_idx))
+            rb.branch.n_regs += 1
+            added = 1 + match_plan.run()
+            # materialize matching registers on routes (keep manually
+            # placed sites)
+            for key2, rb2 in design.routes.items():
+                want = rb2.branch.n_regs
+                have = len(rb2.reg_hops)
+                if have < want:
+                    for idx in _add_regs_balanced(rb2, want - have):
+                        delta.added.append((key2, idx))
+            engine.notify_added(delta.added)
+
+            if p.register_budget is not None and \
+                    design.netlist.added_registers() > p.register_budget:
+                delta.undo(design)
+                engine.notify_removed(delta.added)
+                reason = "register_budget"
+                break
+
+            new_rep = _analyze(engine)
+            reverted = False
+            if new_rep.critical_path_ns > rep.critical_path_ns:
+                delta.undo(design)
+                engine.notify_removed(delta.added)
+                new_rep = rep
+                reverted = True
+            # budget hook: consulted on every round that changed the design,
+            # *before* the convergence check — a no-improvement round still
+            # spends a register and must not slip past an external budget
+            if round_hook is not None and not reverted \
+                    and not round_hook(design, new_rep):
+                engine.resync()     # the hook may have rewound the design
+                rep = _analyze(engine)
+                history.append(rep.critical_path_ns)
+                reason = "round_hook"
+                break
+            if new_rep.critical_path_ns >= \
+                    rep.critical_path_ns - p.min_improvement:
+                stall += 1
+                if stall >= p.patience:
+                    rep = new_rep
+                    history.append(rep.critical_path_ns)
+                    reason = "converged"
+                    break
+            else:
+                stall = 0
+            rep = new_rep
+            history.append(rep.critical_path_ns)
 
     added_total = design.netlist.added_registers()
     return PostPnRResult(

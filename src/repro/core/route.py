@@ -79,12 +79,17 @@ def _astar(fabric: Fabric, srcs: Dict[Tile, float], dst: Tile,
 
 def route(nl: Netlist, placement: Dict[str, Tile], fabric: Fabric,
           params: Optional[RouteParams] = None,
-          region: Optional[Region] = None) -> RoutedDesign:
+          region: Optional[Region] = None,
+          stats: Optional[dict] = None) -> RoutedDesign:
     """Route every branch; with ``region`` (multi-app fabric sharing) the
     routes are *fenced*: any edge that would cross the region boundary into
     a foreign sub-fabric costs ``inf``, so the search never relaxes through
     it and no hop of a resident's net can consume a neighbour's routing
-    tracks.  A post-route containment check backstops the fence."""
+    tracks.  A post-route containment check backstops the fence.
+
+    ``stats`` (optional dict) is filled with the negotiation's counters:
+    ``iterations``, and on the jax backend ``kernel_calls`` and the sorted
+    padded ``(D, S)`` kernel ``shapes``."""
     p = params or RouteParams()
     backend = p.resolved_backend()
     width_class = lambda w: 16 if w >= 16 else 1
@@ -97,7 +102,7 @@ def route(nl: Netlist, placement: Dict[str, Tile], fabric: Fabric,
     if backend == "jax":
         from .route_jax import route_trees_jax
         tree_paths = route_trees_jax(nl, placement, fabric, by_driver, p,
-                                     region)
+                                     region, stats=stats)
         return _finalize(nl, placement, fabric, by_driver, tree_paths,
                          region)
 
@@ -195,6 +200,8 @@ def route(nl: Netlist, placement: Dict[str, Tile], fabric: Fabric,
             raise RuntimeError(
                 f"{nl.name}: routing did not converge, {len(over)} overused "
                 f"boundaries after {p.max_iters} iterations")
+    if stats is not None:
+        stats["iterations"] = it + 1
 
     return _finalize(nl, placement, fabric, by_driver, tree_paths, region)
 

@@ -42,6 +42,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..runtime.spans import span
 from .interconnect import Fabric, Region, Tile, manhattan
 from .netlist import Branch, Netlist
 
@@ -175,11 +176,18 @@ def _pad_pow2(k: int, lo: int = 1) -> int:
 
 def route_trees_jax(nl: Netlist, placement: Dict[str, Tile], fabric: Fabric,
                     by_driver: Dict[str, List[Branch]], p,
-                    region: Optional[Region]) -> Dict[
+                    region: Optional[Region],
+                    stats: Optional[dict] = None) -> Dict[
                         str, Dict[Tuple[str, str, int], List[Tile]]]:
     """Run the full negotiation loop with the batched kernel; returns the
     same ``driver -> branch-key -> tile path`` map the Python router builds
-    (``route()`` finalizes both identically)."""
+    (``route()`` finalizes both identically).
+
+    Each negotiation iteration is a ``cascade.route.iter`` span and each
+    kernel call, through the read-back of its results, a
+    ``cascade.route.kernel`` span inside it.  ``stats`` (optional dict)
+    gets ``iterations``, ``kernel_calls`` and the sorted padded ``(D, S)``
+    shapes the kernel ran at."""
     T, out_nbr, in_src, in_dir = _tile_tables(fabric, region)
     cols = fabric.cols
     tid = lambda t: (t[0] + 1) * cols + t[1]
@@ -214,71 +222,81 @@ def route_trees_jax(nl: Netlist, placement: Dict[str, Tile], fabric: Fabric,
 
     drivers = list(by_driver)
     dirty = set(drivers)
+    calls = 0
+    shapes = set()
     for it in range(p.max_iters):
-        # rip up every dirty driver first: the whole batch prices against
-        # one frozen usage snapshot (parallel PathFinder)
-        for drv in dirty:
-            if drv in tree_edges:
-                wc = drv_wc[drv]
-                for t, d in tree_edges[drv]:
-                    usage[wc][t, d] -= 1
-        for wc in (1, 16):
-            batch = [d for d in drivers if d in dirty and drv_wc[d] == wc]
-            if not batch:
-                continue
-            S = _pad_pow2(max(len(order[d]) for d in batch))
-            D = _pad_pow2(len(batch))
-            drv_tile = np.zeros(D, dtype=np.int32)
-            sink_tiles = np.full((D, S), -1, dtype=np.int32)
-            for i, drv in enumerate(batch):
-                drv_tile[i] = tid(placement[drv])
-                for s, b in enumerate(order[drv]):
-                    sink_tiles[i, s] = tid(placement[b.sink])
-            cost_out = _edge_costs(usage[wc], history[wc], valid,
-                                   cap[wc], p.present_fac)
-            kernel = _jitted_router(T, D, S)
-            paths, dcosts = kernel(jnp.asarray(in_src), jnp.asarray(in_dir),
-                                   jnp.asarray(cost_out),
-                                   jnp.asarray(drv_tile),
-                                   jnp.asarray(sink_tiles))
-            paths = np.asarray(paths)
-            dcosts = np.asarray(dcosts)
-            for i, drv in enumerate(batch):
-                tree: Dict[Tile, List[Tile]] = {
-                    placement[drv]: [placement[drv]]}
-                out: Dict[Tuple[str, str, int], List[Tile]] = {}
-                for s, b in enumerate(order[drv]):
-                    if not math.isfinite(dcosts[i, s]):
-                        raise RuntimeError(f"unroutable: {drv} -> {b.sink}")
-                    raw = paths[i, s]
-                    part = [untid(int(x)) for x in raw[raw >= 0]][::-1]
-                    join = part[0]
-                    out[b.key] = tree[join][:-1] + part
-                    for j in range(len(part) - 1):
-                        t = part[j + 1]
-                        if t not in tree:
-                            tree[t] = tree[part[j]] + [t]
-                tree_paths[drv] = out
-                tree_edges[drv] = edges_of(out)
-                for t, d in tree_edges[drv]:
-                    usage[wc][t, d] += 1
+        with span("cascade.route.iter", iter=it, dirty=len(dirty)):
+            # rip up every dirty driver first: the whole batch prices against
+            # one frozen usage snapshot (parallel PathFinder)
+            for drv in dirty:
+                if drv in tree_edges:
+                    wc = drv_wc[drv]
+                    for t, d in tree_edges[drv]:
+                        usage[wc][t, d] -= 1
+            for wc in (1, 16):
+                batch = [d for d in drivers if d in dirty and drv_wc[d] == wc]
+                if not batch:
+                    continue
+                S = _pad_pow2(max(len(order[d]) for d in batch))
+                D = _pad_pow2(len(batch))
+                drv_tile = np.zeros(D, dtype=np.int32)
+                sink_tiles = np.full((D, S), -1, dtype=np.int32)
+                for i, drv in enumerate(batch):
+                    drv_tile[i] = tid(placement[drv])
+                    for s, b in enumerate(order[drv]):
+                        sink_tiles[i, s] = tid(placement[b.sink])
+                cost_out = _edge_costs(usage[wc], history[wc], valid,
+                                       cap[wc], p.present_fac)
+                kernel = _jitted_router(T, D, S)
+                with span("cascade.route.kernel", T=T, D=D, S=S):
+                    paths, dcosts = kernel(
+                        jnp.asarray(in_src), jnp.asarray(in_dir),
+                        jnp.asarray(cost_out), jnp.asarray(drv_tile),
+                        jnp.asarray(sink_tiles))
+                    paths = np.asarray(paths)
+                    dcosts = np.asarray(dcosts)
+                calls += 1
+                shapes.add((D, S))
+                for i, drv in enumerate(batch):
+                    tree: Dict[Tile, List[Tile]] = {
+                        placement[drv]: [placement[drv]]}
+                    out: Dict[Tuple[str, str, int], List[Tile]] = {}
+                    for s, b in enumerate(order[drv]):
+                        if not math.isfinite(dcosts[i, s]):
+                            raise RuntimeError(
+                                f"unroutable: {drv} -> {b.sink}")
+                        raw = paths[i, s]
+                        part = [untid(int(x)) for x in raw[raw >= 0]][::-1]
+                        join = part[0]
+                        out[b.key] = tree[join][:-1] + part
+                        for j in range(len(part) - 1):
+                            t = part[j + 1]
+                            if t not in tree:
+                                tree[t] = tree[part[j]] + [t]
+                    tree_paths[drv] = out
+                    tree_edges[drv] = edges_of(out)
+                    for t, d in tree_edges[drv]:
+                        usage[wc][t, d] += 1
 
-        over = {wc: usage[wc] > cap[wc] for wc in (1, 16)}
-        if not any(o.any() for o in over.values()):
-            break
-        dirty = set()
-        for wc in (1, 16):
-            if not over[wc].any():
-                continue
-            history[wc] += np.where(over[wc], p.history_fac, 0.0)
-            hot = {(t, d) for t, d in zip(*np.nonzero(over[wc]))}
-            for drv in drivers:
-                if drv_wc[drv] == wc and tree_edges[drv] & hot:
-                    dirty.add(drv)
+            over = {wc: usage[wc] > cap[wc] for wc in (1, 16)}
+            if not any(o.any() for o in over.values()):
+                break
+            dirty = set()
+            for wc in (1, 16):
+                if not over[wc].any():
+                    continue
+                history[wc] += np.where(over[wc], p.history_fac, 0.0)
+                hot = {(t, d) for t, d in zip(*np.nonzero(over[wc]))}
+                for drv in drivers:
+                    if drv_wc[drv] == wc and tree_edges[drv] & hot:
+                        dirty.add(drv)
     else:
         n_over = int(sum(o.sum() for o in over.values()))
         if n_over:
             raise RuntimeError(
                 f"{nl.name}: routing did not converge, {n_over} overused "
                 f"boundaries after {p.max_iters} iterations")
+    if stats is not None:
+        stats.update(iterations=it + 1, kernel_calls=calls,
+                     shapes=sorted(shapes))
     return tree_paths

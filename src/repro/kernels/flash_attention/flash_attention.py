@@ -124,5 +124,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, d), jnp.float32),      # weighted accumulator
         ],
         interpret=resolve_interpret(interpret),
+        name="flash_attention",
     )(qp, kp, vp)
     return out.reshape(b, h, sqp, d)[:, :, :sq, :]

@@ -113,5 +113,6 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((b * kv, g, hd), q.dtype),
         interpret=resolve_interpret(interpret),
+        name="flash_decode",
     )(lens, qf, kf, vf)
     return out.reshape(b, kv, g, hd)

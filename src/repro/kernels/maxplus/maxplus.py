@@ -79,5 +79,6 @@ def maxplus_matmul(a: jax.Array, b: jax.Array, *, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), dtype),
         interpret=resolve_interpret(interpret),
+        name="maxplus_matmul",
     )(a, b)
     return out[:m, :n]

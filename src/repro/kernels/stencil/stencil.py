@@ -60,5 +60,6 @@ def stencil3x3(x: jax.Array, w: jax.Array, *, bh: int = 128,
         out_specs=pl.BlockSpec((bh, width), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((hp, width), x.dtype),
         interpret=resolve_interpret(interpret),
+        name="stencil3x3",
     )(x0, x1, x2, w)
     return out[:h]

@@ -179,6 +179,7 @@ def _blockwise_attention(q, k, v, *, causal: bool, bq: int = 512,
     return out[:, :sq].astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def attention(p: Tree, x: jax.Array, cfg, *, positions: jax.Array,
               causal: bool = True, memory: Optional[jax.Array] = None,
               cache: Optional[Tree] = None, cache_pos=None,
@@ -215,10 +216,11 @@ def attention(p: Tree, x: jax.Array, cfg, *, positions: jax.Array,
     if cache is not None:
         kc = jnp.moveaxis(k, 1, 2).astype(cache["k"].dtype)   # [B,KV,S,hd]
         vc = jnp.moveaxis(v, 1, 2).astype(cache["v"].dtype)
-        ck = jax.lax.dynamic_update_slice(
-            cache["k"], kc, (0, 0, cache_pos, 0))
-        cv = jax.lax.dynamic_update_slice(
-            cache["v"], vc, (0, 0, cache_pos, 0))
+        with jax.named_scope("kv_update"):
+            ck = jax.lax.dynamic_update_slice(
+                cache["k"], kc, (0, 0, cache_pos, 0))
+            cv = jax.lax.dynamic_update_slice(
+                cache["v"], vc, (0, 0, cache_pos, 0))
         new_cache = {"k": ck, "v": cv}
         # causal masking against absolute positions: queries sit at
         # cache_pos..cache_pos+s-1, keys at 0..S_max-1
@@ -311,6 +313,7 @@ def moe_defs(cfg, layers: int = 0) -> Tree:
     }
 
 
+@jax.named_scope("moe")
 def moe_ffn(p: Tree, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
     """Returns (output, load-balance aux loss).  Routing groups = sequences:
     the sort/capacity bookkeeping runs along the unsharded seq axis, so

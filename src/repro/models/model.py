@@ -431,8 +431,9 @@ class LM:
         impl = self._impl(s)
         x, cache = self._stack_with_cache(params, batch, x, positions, cache,
                                           cache_pos=0, impl=impl)
-        x = Lyr.apply_norm(params["final_norm"], x[:, -1:], cfg.norm_eps)
-        logits = Lyr.unembed(params["embed"], x)
+        with jax.named_scope("head"):
+            x = Lyr.apply_norm(params["final_norm"], x[:, -1:], cfg.norm_eps)
+            logits = Lyr.unembed(params["embed"], x)
         return logits[:, 0], cache
 
     def decode_step(self, params: Tree, batch: Dict[str, jax.Array],
@@ -446,14 +447,19 @@ class LM:
         x = Lyr.embed(params["embed"], tokens)
         x, cache = self._stack_with_cache(params, batch, x, positions, cache,
                                           cache_pos=pos, impl="einsum")
-        x = Lyr.apply_norm(params["final_norm"], x, cfg.norm_eps)
-        logits = Lyr.unembed(params["embed"], x)
+        with jax.named_scope("head"):
+            x = Lyr.apply_norm(params["final_norm"], x, cfg.norm_eps)
+            logits = Lyr.unembed(params["embed"], x)
         return logits[:, 0], cache
 
     # ------------------------------------------------------------------
 
+    @jax.named_scope("layers")
     def _stack_with_cache(self, params, batch, x, positions, cache,
                           cache_pos, impl):
+        """The layer loop over the cache: the scans with the cache's
+        stacking and reshaping around them, all under the ``layers``
+        scope."""
         cfg = self.cfg
         fam = cfg.family
 
