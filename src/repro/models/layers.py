@@ -182,13 +182,18 @@ def _blockwise_attention(q, k, v, *, causal: bool, bq: int = 512,
 @jax.named_scope("attention")
 def attention(p: Tree, x: jax.Array, cfg, *, positions: jax.Array,
               causal: bool = True, memory: Optional[jax.Array] = None,
-              cache: Optional[Tree] = None, cache_pos=None,
+              cache: Optional[Tree] = None, cache_pos=None, cache_layer=None,
               impl: str = "einsum") -> Tuple[jax.Array, Optional[Tree]]:
     """Self- or cross-attention with optional KV cache.
 
     x: [B, S, D].  memory: [B, T, D] for cross-attention (keys/values come
     from memory and are not rope'd or cached causally).  cache: dict with
-    "k"/"v" [B, KV, S_max, hd] updated at cache_pos.
+    "k"/"v" [B, KV, hd, T] updated at cache_pos: T is last, because for a
+    64-wide head dim the chip's default layout of [.., T, hd] puts T minor
+    anyway, and with T last that layout is row-major, so the flash_decode
+    kernel reads the cache without a relayout.  With ``cache_layer`` cache
+    holds every layer's [L, B, KV, hd, T]: this layer's tokens are written
+    in place at that index, and the kernel reads the layer where it lies.
     """
     b, s, _ = x.shape
     hd = cfg.resolved_head_dim
@@ -214,16 +219,17 @@ def attention(p: Tree, x: jax.Array, cfg, *, positions: jax.Array,
 
     new_cache = None
     if cache is not None:
-        kc = jnp.moveaxis(k, 1, 2).astype(cache["k"].dtype)   # [B,KV,S,hd]
-        vc = jnp.moveaxis(v, 1, 2).astype(cache["v"].dtype)
+        kc = jnp.moveaxis(k, 1, 3).astype(cache["k"].dtype)   # [B,KV,hd,S]
+        vc = jnp.moveaxis(v, 1, 3).astype(cache["v"].dtype)
+        at = (0, 0, 0, cache_pos)
+        if cache_layer is not None:
+            kc, vc, at = kc[None], vc[None], (cache_layer,) + at
         with jax.named_scope("kv_update"):
-            ck = jax.lax.dynamic_update_slice(
-                cache["k"], kc, (0, 0, cache_pos, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cache["v"], vc, (0, 0, cache_pos, 0))
+            ck = jax.lax.dynamic_update_slice(cache["k"], kc, at)
+            cv = jax.lax.dynamic_update_slice(cache["v"], vc, at)
         new_cache = {"k": ck, "v": cv}
         # causal masking against absolute positions: queries sit at
-        # cache_pos..cache_pos+s-1, keys at 0..S_max-1
+        # cache_pos..cache_pos+s-1, keys at 0..T-1
         q_off = cache_pos
     else:
         q_off = 0
@@ -234,14 +240,18 @@ def attention(p: Tree, x: jax.Array, cfg, *, positions: jax.Array,
         # streams the cache through VMEM once, no HBM score traffic
         from repro.kernels.flash_decode import flash_decode
         lens = jnp.full((b,), 0, jnp.int32) + (cache_pos + 1)
-        out = flash_decode(qg[:, 0], ck, cv, lens)[:, None]   # [B,1,KV,G,hd]
+        out = flash_decode(qg[:, 0], ck, cv, lens,
+                           cache_layer)[:, None]             # [B,1,KV,G,hd]
     elif cache is not None:
-        # attention directly in cache layout [B, KV, T, hd]: transposing
+        # attention directly in cache layout [B, KV, hd, T]: transposing
         # the full cache (moveaxis) would read+write it twice per step,
         # which dominates decode HBM traffic
-        sc = jnp.einsum("bskgd,bktd->bkgst", qg, ck,
+        if cache_layer is not None:
+            ck = jax.lax.dynamic_index_in_dim(ck, cache_layer, 0, False)
+            cv = jax.lax.dynamic_index_in_dim(cv, cache_layer, 0, False)
+        sc = jnp.einsum("bkdt,bskgd->bkgst", ck, qg,
                         preferred_element_type=jnp.float32) / math.sqrt(hd)
-        t = ck.shape[2]
+        t = ck.shape[-1]
         rows = q_off + jnp.arange(s)[:, None]
         cols = jnp.arange(t)[None, :]
         mask = cols < (cache_pos + s)            # frontier
@@ -249,7 +259,7 @@ def attention(p: Tree, x: jax.Array, cfg, *, positions: jax.Array,
             mask = mask & (rows >= cols)
         sc = jnp.where(mask[None, None, None], sc, -1e30)
         pr = jax.nn.softmax(sc, axis=-1)
-        out = jnp.einsum("bkgst,bktd->bskgd", pr,
+        out = jnp.einsum("bkgst,bkdt->bskgd", pr,
                          cv.astype(jnp.float32)).astype(x.dtype)
     elif impl == "flash":
         o = gqa_attention(jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2),

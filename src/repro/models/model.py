@@ -168,14 +168,15 @@ class LM:
 
     def _dense_block(self, p: Tree, x, positions, *, impl, causal=True,
                      memory=None, cache=None, cache_pos=None,
-                     xmemory_kv=None):
+                     cache_layer=None, xmemory_kv=None):
         cfg = self.cfg
         new_cache = None
         if "attn" in p:
             h = Lyr.apply_norm(p["ln1"], x, cfg.norm_eps)
             a, new_cache = Lyr.attention(
                 p["attn"], h, cfg, positions=positions, causal=causal,
-                cache=cache, cache_pos=cache_pos, impl=impl)
+                cache=cache, cache_pos=cache_pos, cache_layer=cache_layer,
+                impl=impl)
             x = x + a
         aux = jnp.zeros((), jnp.float32)
         if "xattn" in p:
@@ -381,13 +382,19 @@ class LM:
         fam = cfg.family
         hd, hkv = cfg.resolved_head_dim, cfg.num_kv_heads
 
-        def kv(layers, seq):
-            ax = ("layers", "cache_batch", "cache_heads", "cache_seq",
-                  "cache_hd")
-            return {
-                "k": ParamDef((layers, batch, hkv, seq, hd), ax, init="zeros"),
-                "v": ParamDef((layers, batch, hkv, seq, hd), ax, init="zeros"),
-            }
+        def kv(layers, seq, cross=False):
+            # self-attention caches are [L, B, KV, hd, T], T last (see
+            # layers.attention); cross K/V keep _cross_kv's [.., T, hd]
+            if cross:
+                ax = ("layers", "cache_batch", "cache_heads", "cache_seq",
+                      "cache_hd")
+                shape = (layers, batch, hkv, seq, hd)
+            else:
+                ax = ("layers", "cache_batch", "cache_heads", "cache_hd",
+                      "cache_seq")
+                shape = (layers, batch, hkv, hd, seq)
+            return {"k": ParamDef(shape, ax, init="zeros"),
+                    "v": ParamDef(shape, ax, init="zeros")}
 
         if fam == "ssm":
             return Ssm.rwkv_state_defs(cfg, batch, L)
@@ -397,11 +404,13 @@ class LM:
                     "shared": kv(groups, max_seq)}
         if fam == "audio":
             return {"self": kv(L, max_seq),
-                    "cross": kv(L, self.frames_len(max_seq, decode=True))}
+                    "cross": kv(L, self.frames_len(max_seq, decode=True),
+                                cross=True)}
         if fam == "vlm":
             n_cross = L // cfg.cross_attn_every
             return {"self": kv(L, max_seq),
-                    "cross": kv(n_cross, cfg.num_image_tokens)}
+                    "cross": kv(n_cross, cfg.num_image_tokens,
+                                cross=True)}
         return {"self": kv(L, max_seq)}
 
     def init_cache(self, batch: int, max_seq: int) -> Tree:
@@ -459,7 +468,11 @@ class LM:
                           cache_pos, impl):
         """The layer loop over the cache: the scans with the cache's
         stacking and reshaping around them, all under the ``layers``
-        scope."""
+        scope.  Self-attention caches are [L, B, KV, hd, T] (T last: see
+        ``layers.attention``).  The dense and moe stacks carry the whole
+        cache through the scan, so no layer's cache is sliced out, copied
+        or re-stacked; the other families pass each layer's cache as a
+        slice of the scan's xs and its update as a slice of its ys."""
         cfg = self.cfg
         fam = cfg.family
 
@@ -553,51 +566,37 @@ class LM:
                 body, x, (params["blocks"], cache["self"], xkv))
             return x, {"self": c2, "cross": xkv2}
 
-        # dense / moe
+        # dense / moe: the stacked cache is the scans' carry and the layer
+        # index rides in their xs, so each layer writes its tokens in place
+        # at that index and reads its cache where it lies
+        def block(carry, xs):
+            (x, c), (p, layer) = carry, xs
+            y, c, _ = self._dense_block(p, x, positions, impl=impl, cache=c,
+                                        cache_pos=cache_pos,
+                                        cache_layer=layer)
+            return (y, c), None
+
+        carry = (x, cache["self"])
         if fam == "moe":
             period = cfg.moe_layer_period
             n_moe = cfg.num_layers // period
-            mcache = _stack_reshape(
-                cache["self"], n_moe, period)
 
-            def group(x, xs):
-                ps, cs = xs
-                caches_out = []
-
-                def inner(x, pc):
-                    p, c = pc
-                    y, c2, _ = self._dense_block(p, x, positions, impl=impl,
-                                                 cache=c, cache_pos=cache_pos)
-                    return y, c2
+            def group(carry, xs):
+                ps, g = xs
+                first = g * period
                 if period > 1:
-                    dense_c = jax.tree.map(lambda a: a[:period - 1], cs)
-                    x, dc2 = self._scan(inner, x, (ps["dense"], dense_c))
-                moe_c = jax.tree.map(lambda a: a[period - 1], cs)
-                y, mc2, _ = self._dense_block(ps["moe"], x, positions,
-                                              impl=impl, cache=moe_c,
-                                              cache_pos=cache_pos)
-                if period > 1:
-                    c2 = jax.tree.map(
-                        lambda a, b: jnp.concatenate([a, b[None]], 0),
-                        dc2, mc2)
-                else:
-                    c2 = jax.tree.map(lambda a: a[None], mc2)
-                return y, c2
+                    carry, _ = self._scan(
+                        block, carry,
+                        (ps["dense"], first + jnp.arange(period - 1)))
+                return block(carry, (ps["moe"], first + period - 1))
 
             xs: Dict[str, Any] = {"moe": params["moe_blocks"]}
             if period > 1:
                 xs["dense"] = _stack_reshape(
                     params["blocks"], n_moe, period - 1)
-            x, c2 = self._scan(group, x, (xs, mcache))
-            new_c = jax.tree.map(
-                lambda a: a.reshape((cfg.num_layers,) + a.shape[2:]), c2)
-            return x, {"self": new_c}
-
-        def body(x, xs):
-            p, c = xs
-            y, c2, _ = self._dense_block(p, x, positions, impl=impl,
-                                         cache=c, cache_pos=cache_pos)
-            return y, c2
-
-        x, c2 = self._scan(body, x, (params["blocks"], cache["self"]))
-        return x, {"self": c2}
+            carry, _ = self._scan(group, carry, (xs, jnp.arange(n_moe)))
+        else:
+            carry, _ = self._scan(
+                block, carry, (params["blocks"], jnp.arange(cfg.num_layers)))
+        x, c = carry
+        return x, {"self": c}

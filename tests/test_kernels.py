@@ -161,8 +161,8 @@ def test_flash_decode_shapes(b, kv, g, t, hd, bk):
     from repro.kernels.flash_decode import flash_decode, flash_decode_ref
     rng = np.random.default_rng(b * t + hd)
     q = jnp.asarray(rng.normal(size=(b, kv, g, hd)).astype("float32"))
-    k = jnp.asarray(rng.normal(size=(b, kv, t, hd)).astype("float32"))
-    v = jnp.asarray(rng.normal(size=(b, kv, t, hd)).astype("float32"))
+    k = jnp.asarray(rng.normal(size=(b, kv, hd, t)).astype("float32"))
+    v = jnp.asarray(rng.normal(size=(b, kv, hd, t)).astype("float32"))
     lens = jnp.asarray(rng.integers(1, t, size=(b,)).astype("int32"))
     got = flash_decode(q, k, v, lens, bk=bk)
     want = flash_decode_ref(q, k, v, lens)
@@ -173,8 +173,8 @@ def test_flash_decode_bf16():
     from repro.kernels.flash_decode import flash_decode, flash_decode_ref
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.normal(size=(2, 2, 4, 64))).astype(jnp.bfloat16)
-    k = jnp.asarray(rng.normal(size=(2, 2, 200, 64))).astype(jnp.bfloat16)
-    v = jnp.asarray(rng.normal(size=(2, 2, 200, 64))).astype(jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(2, 2, 64, 200))).astype(jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(2, 2, 64, 200))).astype(jnp.bfloat16)
     lens = jnp.asarray([150, 37], jnp.int32)
     got = flash_decode(q, k, v, lens)
     want = flash_decode_ref(q, k, v, lens)
@@ -189,18 +189,38 @@ def test_flash_decode_matches_model_cache_attention():
     rng = np.random.default_rng(2)
     b, kv, g, t, hd = 2, 2, 2, 64, 32
     q = jnp.asarray(rng.normal(size=(b, 1, kv, g, hd)).astype("float32"))
-    ck = jnp.asarray(rng.normal(size=(b, kv, t, hd)).astype("float32"))
-    cv = jnp.asarray(rng.normal(size=(b, kv, t, hd)).astype("float32"))
+    ck = jnp.asarray(rng.normal(size=(b, kv, hd, t)).astype("float32"))
+    cv = jnp.asarray(rng.normal(size=(b, kv, hd, t)).astype("float32"))
     pos = 40
     # model path (layers.attention cache branch math)
     import math as _m
-    sc = jnp.einsum("bskgd,bktd->bkgst", q, ck) / _m.sqrt(hd)
+    sc = jnp.einsum("bskgd,bkdt->bkgst", q, ck) / _m.sqrt(hd)
     mask = (jnp.arange(t) < pos + 1)[None, None, None, None, :]
     pr = jax.nn.softmax(jnp.where(mask, sc, -1e30), axis=-1)
-    want = jnp.einsum("bkgst,bktd->bskgd", pr, cv)[:, 0]
+    want = jnp.einsum("bkgst,bkdt->bskgd", pr, cv)[:, 0]
     got = flash_decode_ref(q[:, 0], ck, cv,
                            jnp.full((b,), pos + 1, jnp.int32))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("frontier", ["one", "mid_block", "full"])
+@pytest.mark.parametrize("t,bk", [(512, 256), (300, 128)])
+def test_flash_decode_stacked_cache_equals_layer_slice(t, bk, frontier):
+    """The stacked [L, B, KV, hd, T] cache read at a layer index gives the
+    4-D call on that layer's slice bit for bit, when bk divides T and when
+    the last block is partial."""
+    from repro.kernels.flash_decode import flash_decode
+    rng = np.random.default_rng(t + bk)
+    n_layers, b, kv, g, hd = 3, 2, 2, 4, 64
+    q = jnp.asarray(rng.normal(size=(b, kv, g, hd)).astype("float32"))
+    k, v = (jnp.asarray(rng.normal(size=(n_layers, b, kv, hd, t))
+                        .astype("float32")) for _ in range(2))
+    at = {"one": 1, "mid_block": (t // bk) * bk - bk // 2, "full": t}[frontier]
+    lens = jnp.asarray([at, max(1, at - 1)], jnp.int32)
+    for layer in range(n_layers):
+        got = flash_decode(q, k, v, lens, jnp.int32(layer), bk=bk)
+        want = flash_decode(q, k[layer], v[layer], lens, bk=bk)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_blockwise_matches_flash_and_ref():

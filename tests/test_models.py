@@ -198,10 +198,15 @@ def test_blockwise_impl_matches_einsum_in_model():
                                rtol=6e-2, atol=6e-2)
 
 
-def test_flash_decode_kernel_in_model_decode():
+@pytest.mark.parametrize("arch,edits", [
+    ("llama3-8b", {}), ("granite-moe-1b-a400m", {}),
+    ("granite-moe-1b-a400m", {"moe_layer_period": 2}),
+    ("llama3-8b", {"scan_layers": False})])
+def test_flash_decode_kernel_in_model_decode(arch, edits):
     """use_flash routes single-token decode through the Pallas flash-decode
-    kernel; logits must match the einsum cache path exactly."""
-    cfg = get_config("llama3-8b").smoke()
+    kernel, with the stacked cache carried through the layer scan; logits
+    must match the einsum cache path, and so must the updated cache."""
+    cfg = get_config(arch).replace(**edits).smoke()
     m_e, m_f = LM(cfg), LM(cfg.replace(use_flash=True))
     params = m_e.init(jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
@@ -209,13 +214,17 @@ def test_flash_decode_kernel_in_model_decode():
     c1, c2 = m_e.init_cache(2, 20), m_f.init_cache(2, 20)
     _, c1 = m_e.prefill(params, {"tokens": toks[:, :15]}, c1)
     _, c2 = m_f.prefill(params, {"tokens": toks[:, :15]}, c2)
-    d1, _ = m_e.decode_step(params, {"tokens": toks[:, 15:]}, c1,
-                            jnp.int32(15))
-    d2, _ = m_f.decode_step(params, {"tokens": toks[:, 15:]}, c2,
-                            jnp.int32(15))
+    d1, c1 = m_e.decode_step(params, {"tokens": toks[:, 15:]}, c1,
+                             jnp.int32(15))
+    d2, c2 = m_f.decode_step(params, {"tokens": toks[:, 15:]}, c2,
+                             jnp.int32(15))
     np.testing.assert_allclose(np.asarray(d1, np.float32),
                                np.asarray(d2, np.float32),
                                rtol=2e-2, atol=2e-2)
+    for a, b in zip(jax.tree.leaves(c1), jax.tree.leaves(c2)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=2e-2, atol=2e-2)
 
 
 def test_cell_runnability_covers_40():

@@ -104,11 +104,39 @@ def test_flash_decode_compiles_for_v5e_at_granite_widths(one_chip):
     from repro.kernels.flash_decode import flash_decode
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     q = sds((4, 8, 2, 64), jnp.bfloat16)
-    cache = sds((4, 8, 2048, 64), jnp.bfloat16)
+    cache = sds((4, 8, 64, 2048), jnp.bfloat16)
     lengths = sds((4,), jnp.int32)
     compiled = flash_decode.lower(q, cache, cache, lengths,
                                   interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _granite_decode_step(chip, monkeypatch, *, layers, b, s, donate=False):
+    """The serve path's decode step at granite's widths, compiled for
+    ``chip`` with the Mosaic flash_decode kernel (as on the chip, not the
+    CPU's interpreter)."""
+    import importlib
+    from repro.configs import get_config
+    from repro.models import LM
+    fd = importlib.import_module("repro.kernels.flash_decode.flash_decode")
+    monkeypatch.setattr(fd, "resolve_interpret", lambda i: False)
+    fd.flash_decode.clear_cache()
+    try:
+        cfg = get_config("granite-moe-1b-a400m").replace(num_layers=layers,
+                                                         use_flash=True)
+        model = LM(cfg)
+        on_chip = lambda tree: jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=chip), tree)
+        step = jax.jit(model.decode_step,
+                       donate_argnums=(2,) if donate else ())
+        return step.lower(
+            on_chip(model.shapes()),
+            {"tokens": on_chip(jax.ShapeDtypeStruct((b, 1), jnp.int32))},
+            on_chip(model.cache_shapes(b, s)),
+            on_chip(jax.ShapeDtypeStruct((), jnp.int32))).compile()
+    finally:
+        fd.flash_decode.clear_cache()
 
 
 def test_granite_decode_step_keeps_flash_decode_kernel_name(one_chip,
@@ -116,28 +144,52 @@ def test_granite_decode_step_keeps_flash_decode_kernel_name(one_chip,
     """The serve path's decode step, at granite's widths, holds the Pallas
     kernel as a custom call whose name (the op's name in a TPU trace)
     starts with ``flash_decode``: the benchmark's kernel time reads it."""
-    import importlib
-    from repro.configs import get_config
-    from repro.models import LM
-    fd = importlib.import_module("repro.kernels.flash_decode.flash_decode")
-    # compile the Mosaic kernel, as on the chip, not the CPU's interpreter
-    monkeypatch.setattr(fd, "resolve_interpret", lambda i: False)
-    fd.flash_decode.clear_cache()
-    try:
-        cfg = get_config("granite-moe-1b-a400m").replace(num_layers=2,
-                                                         use_flash=True)
-        model = LM(cfg)
-        on_chip = lambda tree: jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-        b, s = 4, 1024
-        text = jax.jit(model.decode_step).lower(
-            on_chip(model.shapes()),
-            {"tokens": on_chip(jax.ShapeDtypeStruct((b, 1), jnp.int32))},
-            on_chip(model.cache_shapes(b, s)),
-            on_chip(jax.ShapeDtypeStruct((), jnp.int32))).compile().as_text()
-    finally:
-        fd.flash_decode.clear_cache()
+    text = _granite_decode_step(one_chip, monkeypatch, layers=2, b=4,
+                                s=1024).as_text()
     kernels = [line.split("=", 1)[0].strip() for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     assert kernels and all(k.startswith("%flash_decode") for k in kernels)
+
+
+#: ops that may hold a cache-sized value without moving the cache
+_CACHE_SIZED_OK = {"parameter", "bitcast", "get-tuple-element", "tuple",
+                   "while", "dynamic-update-slice"}
+
+
+def test_granite_decode_step_moves_no_cache(one_chip, monkeypatch):
+    """At the benchmark's decode shape (B 48, T 1536, the cache donated)
+    the step reads and writes the stacked KV cache in place: no op but a
+    parameter, bitcast, tuple, loop or the token's dynamic-update-slice
+    holds a value the size of the cache or of any number of its layers (no
+    copy, slice, pad or fresh buffer), nothing cache-sized is temporary,
+    and both cache leaves alias the step's outputs."""
+    import re
+    layers, b, s, kv, hd = 4, 48, 1536, 8, 64
+    compiled = _granite_decode_step(one_chip, monkeypatch, layers=layers,
+                                    b=b, s=s, donate=True)
+    text = compiled.as_text()
+    layer_elems = b * kv * hd * s
+    op = re.compile(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+    moved, entry, cache_params = [], False, set()
+    for line in text.splitlines():
+        entry = line.startswith("ENTRY") or (entry and line != "}")
+        m = op.match(line)
+        if not m:
+            continue
+        n = 1
+        for d in filter(None, m.group(1).split(",")):
+            n *= int(d)
+        if n % layer_elems or not 1 <= n // layer_elems <= layers:
+            continue
+        if m.group(2) not in _CACHE_SIZED_OK:
+            moved.append(line.strip()[:160])
+        if entry and m.group(2) == "parameter" and n == layers * layer_elems:
+            cache_params.add(int(re.search(r"parameter\((\d+)\)",
+                                           line).group(1)))
+    assert not moved, "\n".join(moved)
+    assert len(cache_params) == 2
+    header = text.splitlines()[0]
+    aliased = {int(p) for p in re.findall(r"\((\d+), \{\}, may-alias\)",
+                                          header)}
+    assert cache_params <= aliased, header[:300]
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_elems * 2
