@@ -12,10 +12,12 @@ from .qwen2_5_14b import CONFIG as QWEN25_14B
 from .rwkv6_7b import CONFIG as RWKV6_7B
 from .whisper_small import CONFIG as WHISPER_SMALL
 from .zamba2_2_7b import CONFIG as ZAMBA2_27B
+from .zamba2_7b import CONFIG as ZAMBA2_7B
 
 ARCHS = {c.name: c for c in [
     RWKV6_7B, MINICPM_2B, MISTRAL_LARGE, LLAMA3_8B, QWEN25_14B,
-    ZAMBA2_27B, GRANITE_MOE, LLAMA4_MAVERICK, LLAMA32_VISION, WHISPER_SMALL,
+    ZAMBA2_27B, ZAMBA2_7B, GRANITE_MOE, LLAMA4_MAVERICK, LLAMA32_VISION,
+    WHISPER_SMALL,
 ]}
 
 

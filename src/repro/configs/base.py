@@ -42,6 +42,8 @@ class ModelConfig:
     # -- attention ----------------------------------------------------------
     head_dim: int = 0                # 0 -> d_model // num_heads
     qkv_bias: bool = False
+    attn_scale_mult: float = 1.0     # softmax scale: this / sqrt(head_dim)
+    mlp_act: str = "silu"            # gated MLP: "silu" | "gelu" (exact)
     rope_theta: float = 500_000.0
     causal: bool = True
 
@@ -57,10 +59,17 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     ssm_conv_width: int = 4
+    ssm_groups: int = 1              # mamba2 ngroups: B and C per group of heads
     chunk_size: int = 32             # chunked linear-attention / SSD chunk
 
     # -- hybrid (zamba2) ----------------------------------------------------
-    shared_attn_every: int = 0       # insert the shared attn block every N layers
+    hybrid_layer_ids: Tuple[int, ...] = ()   # Mamba layers a shared block
+                                             # runs before (its output is
+                                             # added to their input)
+    shared_blocks: int = 1           # shared transformer blocks, used in turn
+    shared_concat: bool = False      # block input: concat(hidden, embeddings)
+    adapter_rank: int = 0            # per-invocation LoRA on the MLP's gate/up
+    hybrid_linear: bool = False      # per-invocation linear on the block output
 
     # -- VLM ----------------------------------------------------------------
     cross_attn_every: int = 0        # a cross-attn block after every N self layers
@@ -85,7 +94,8 @@ class ModelConfig:
     sharding_profile: str = "tp"     # "tp" (Megatron TP over model) | "dp"
                                      # (pure data parallel; model axis joins batch)
     sequence_parallel: bool = False  # shard residual seq axis over "model"
-    decode_cache_shard: str = "head_dim"   # "head_dim" | "seq"
+    decode_cache_shard: str = "head_dim"   # "head_dim" | "seq" (einsum
+                                           # decode; flash shards heads)
     use_flash: bool = False          # pallas flash-attention (TPU target path)
     attn_impl: str = "auto"          # "auto" | "einsum" | "blockwise" | "flash"
     optimizer: str = "adamw"         # "adamw" | "adamw_wsd"
@@ -114,12 +124,19 @@ class ModelConfig:
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
 
+    @property
+    def hybrid_layers(self) -> Tuple[int, ...]:
+        """The shared block's invocations: hybrid_layer_ids within depth."""
+        return tuple(i for i in self.hybrid_layer_ids if i < self.num_layers)
+
     # -- parameter counting (used for MODEL_FLOPS = 6*N*D) -------------------
 
-    def _attn_params(self, d: int, heads: int, kv: int, hd: int) -> int:
-        q = d * heads * hd + (heads * hd if self.qkv_bias else 0)
-        k = d * kv * hd + (kv * hd if self.qkv_bias else 0)
-        v = d * kv * hd + (kv * hd if self.qkv_bias else 0)
+    def _attn_params(self, d: int, heads: int, kv: int, hd: int,
+                     d_in: Optional[int] = None) -> int:
+        d_in = d_in or d
+        q = d_in * heads * hd + (heads * hd if self.qkv_bias else 0)
+        k = d_in * kv * hd + (kv * hd if self.qkv_bias else 0)
+        v = d_in * kv * hd + (kv * hd if self.qkv_bias else 0)
         o = heads * hd * d
         return q + k + v + o
 
@@ -135,12 +152,28 @@ class ModelConfig:
         return tm + cm
 
     def _mamba_layer_params(self) -> int:
-        d, di, st = self.d_model, self.d_inner, self.ssm_state
-        in_proj = d * (2 * di + 2 * st + self.ssm_heads)
-        conv = (di + 2 * st) * self.ssm_conv_width
+        d, di = self.d_model, self.d_inner
+        bc = 2 * self.ssm_groups * self.ssm_state
+        in_proj = d * (2 * di + bc + self.ssm_heads)
+        conv = (di + bc) * self.ssm_conv_width
         out = di * d
         extra = 2 * self.ssm_heads + di  # A_log, D, norm
         return in_proj + conv + out + extra
+
+    def _shared_block_params(self) -> int:
+        """One shared transformer block (zamba2): attention over the
+        block's input width, the gated MLP."""
+        d = self.d_model
+        d_in = d * (2 if self.shared_concat else 1)
+        return (self._attn_params(d, self.num_heads, self.num_kv_heads,
+                                  self.resolved_head_dim, d_in=d_in)
+                + self._mlp_params(d, self.d_ff))
+
+    def _hybrid_invocation_params(self) -> int:
+        """What each invocation of a shared block holds of its own: the
+        MLP adapter and the linear on the block's output."""
+        d, r = self.d_model, self.adapter_rank
+        return r * (d + 2 * self.d_ff) + (d * d if self.hybrid_linear else 0)
 
     def param_count(self) -> int:
         """Analytic parameter count (embeddings included once if tied)."""
@@ -149,10 +182,10 @@ class ModelConfig:
         n = emb
         if self.family == "ssm":            # rwkv6
             n += self.num_layers * self._rwkv_layer_params()
-        elif self.family == "hybrid":       # zamba2: mamba stack + one shared attn blk
+        elif self.family == "hybrid":       # zamba2: mamba stack + shared blocks
             n += self.num_layers * self._mamba_layer_params()
-            n += self._attn_params(d, self.num_heads, self.num_kv_heads, hd)
-            n += self._mlp_params(d, self.d_ff)
+            n += self.shared_blocks * self._shared_block_params()
+            n += len(self.hybrid_layers) * self._hybrid_invocation_params()
         elif self.family == "audio":        # whisper enc-dec
             enc = self.encoder_layers * (
                 self._attn_params(d, self.num_heads, self.num_kv_heads, hd)
@@ -196,8 +229,9 @@ class ModelConfig:
     # A tiny config of the same family, for CPU smoke tests.
     def smoke(self) -> "ModelConfig":
         kw: Dict[str, object] = dict(
-            num_layers=max(2, self.moe_layer_period, self.shared_attn_every,
-                           self.cross_attn_every) * 2,
+            # hybrids keep the shared blocks' first invocations in 12 layers
+            num_layers=12 if self.hybrid_layer_ids else max(
+                2, self.moe_layer_period, self.cross_attn_every) * 2,
             d_model=64,
             num_heads=4,
             num_kv_heads=min(self.num_kv_heads, 4) if self.num_kv_heads else 4,
@@ -211,6 +245,8 @@ class ModelConfig:
                       experts_per_token=min(self.experts_per_token, 2))
         if self.ssm_state:
             kw.update(ssm_state=16, ssm_head_dim=16)
+        if self.adapter_rank:
+            kw.update(adapter_rank=8)
         if self.num_image_tokens:
             kw.update(num_image_tokens=16)
         if self.encoder_layers:

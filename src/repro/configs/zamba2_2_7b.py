@@ -1,5 +1,7 @@
 """zamba2-2.7b — hybrid: Mamba2 backbone + one SHARED attention block
-applied periodically (zamba-style weight sharing).
+applied periodically (zamba-style weight sharing), in the program's
+simplified form: one shared block over d_model before every 6th layer,
+no embeddings concat, no adapters, no per-invocation linear.
 [arXiv:2411.15242; hf]
 54L d_model=2560 32H (kv=32) d_ff=10240 vocab=32000, ssm_state=64."""
 
@@ -19,6 +21,6 @@ CONFIG = ModelConfig(
     ssm_head_dim=64,
     ssm_expand=2,
     ssm_conv_width=4,
-    shared_attn_every=6,     # 9 applications of the shared block over 54L
+    hybrid_layer_ids=tuple(range(5, 54, 6)),   # 9 invocations over 54L
     chunk_size=32,
 )

@@ -26,6 +26,7 @@ by count.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
@@ -61,9 +62,9 @@ def layer_costs(cfg: ModelConfig, shape: ShapeSpec, chips_per_stage: int,
                  else cfg._mamba_layer_params())
             fl = 2 * p * tokens * fwd_bwd
             by = 2 * p + tokens * d * 2 * 6
-            if cfg.family == "hybrid" and cfg.shared_attn_every and \
-                    (li + 1) % cfg.shared_attn_every == 0:
-                ap = attn_p + cfg._mlp_params(d, cfg.d_ff)
+            if cfg.family == "hybrid" and li in cfg.hybrid_layers:
+                ap = cfg._shared_block_params() + \
+                    cfg._hybrid_invocation_params()
                 fl += 2 * ap * tokens * fwd_bwd + \
                     4 * tokens * shape.seq_len * cfg.num_heads * hd * 0.5
                 by += 2 * ap
@@ -116,29 +117,34 @@ def _stage_times(costs: Sequence[float], bounds: List[int],
     return out
 
 
-def _refine(costs: Sequence[float], bounds: List[int], bcost: float,
-            max_pass: int = 64) -> List[int]:
-    """Branch-delay-style re-balancing: slide each internal boundary while
-    it lowers the worse of its two adjacent stages (the Cascade matching
-    step after a register insertion)."""
-    bounds = list(bounds)
-    for _ in range(max_pass):
-        improved = False
-        for i in range(1, len(bounds) - 1):
-            def pair_max(b):
-                left = sum(costs[bounds[i - 1]:b])
-                right = sum(costs[b:bounds[i + 1]])
-                return max(left, right)
-            cur = pair_max(bounds[i])
-            for cand in (bounds[i] - 1, bounds[i] + 1):
-                if bounds[i - 1] < cand < bounds[i + 1] and \
-                        pair_max(cand) < cur - 1e-12:
-                    bounds[i] = cand
-                    cur = pair_max(cand)
-                    improved = True
-        if not improved:
-            break
-    return bounds
+def _refine(costs: Sequence[float], bounds: List[int], bcost: float
+            ) -> List[int]:
+    """Re-balancing after the register insertions (the Cascade matching
+    step): the same number of stages, their boundaries placed for the
+    lowest beat.  An exact min-max split of the chain: sliding one
+    boundary at a time stalls where a move helps only once its neighbour
+    has moved too."""
+    n, k = len(costs), len(bounds) - 1
+    pre = np.concatenate([[0.0], np.cumsum(costs)])
+    # best[j][i]: the lowest beat of costs[:i] in j non-final stages
+    best = [[0.0] + [math.inf] * n]
+    back: List[List[int]] = [[0] * (n + 1)]
+    for j in range(1, k):
+        row, arg = [math.inf] * (n + 1), [0] * (n + 1)
+        for i in range(j, n - (k - j) + 1):
+            for p in range(j - 1, i):
+                v = max(best[j - 1][p], pre[i] - pre[p] + bcost)
+                if v < row[i]:
+                    row[i], arg[i] = v, p
+        best.append(row)
+        back.append(arg)
+    cut = min(range(k - 1, n),
+              key=lambda p: max(best[k - 1][p], pre[n] - pre[p]))
+    out = [n]
+    for j in range(k - 1, 0, -1):
+        out.append(cut)
+        cut = back[j][cut]
+    return [0] + out[::-1]
 
 
 def partition(costs: Sequence[float], num_stages: int, bcost: float,

@@ -284,7 +284,12 @@ def probe_unit(cfg) -> int:
     if cfg.family == "moe":
         return cfg.moe_layer_period
     if cfg.family == "hybrid":
-        return cfg.shared_attn_every or 1
+        # a period k when the shared block runs before every k-th layer,
+        # else the whole irregular stack
+        ids = cfg.hybrid_layers
+        k = ids[1] - ids[0] if len(ids) > 1 else 0
+        regular = k and ids == tuple(range(k - 1, cfg.num_layers, k))
+        return k if regular else cfg.num_layers
     if cfg.family == "vlm":
         return cfg.cross_attn_every or 1
     return 1
@@ -349,7 +354,7 @@ def _attn_traffic_correction(cfg, shape, n_model: int, n_batch: int
 
     # how many self-attention layers at this q-length?
     if cfg.family == "hybrid":
-        n_attn = cfg.num_layers // (cfg.shared_attn_every or cfg.num_layers)
+        n_attn = len(cfg.hybrid_layers)
     elif cfg.family in ("dense", "moe", "vlm", "audio"):
         n_attn = cfg.num_layers
     else:

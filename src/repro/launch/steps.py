@@ -68,7 +68,11 @@ def rules_for(cfg: ModelConfig, *, params: bool = False) -> Dict[str, Any]:
         # residual/norm activations shard their seq axis over "model";
         # XLA gathers seq only around attention (Megatron-SP pattern)
         rules["seq"] = "model"
-    if cfg.decode_cache_shard == "seq":
+    if cfg.use_flash:
+        # flash_decode reads whole heads at every position: the cache
+        # shards its heads over the model axis, never head dim or length
+        rules.update(cache_heads="model", cache_hd=None, cache_seq=None)
+    elif cfg.decode_cache_shard == "seq":
         rules.update(cache_seq="model", cache_hd=None)
     if params and cfg.fsdp and cfg.sharding_profile == "tp":
         # ZeRO-3 overlay: weights' embed-ish axes also shard over data

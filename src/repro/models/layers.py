@@ -21,13 +21,15 @@ gather-only (no scatter), which GSPMD partitions cleanly.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import gather_weight as GW, shard
+from repro.distributed.sharding import gather_weight as GW, resolve_spec, shard
 from repro.kernels.flash_attention import gqa_attention
 from .params import ParamDef
 
@@ -89,16 +91,20 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 # attention
 
 
-def attention_defs(cfg, d_model: Optional[int] = None, layers: int = 0) -> Tree:
+def attention_defs(cfg, d_model: Optional[int] = None, layers: int = 0,
+                   d_in: Optional[int] = None) -> Tree:
+    """q/k/v from inputs of width ``d_in`` (default ``d_model``), the
+    output projected to ``d_model``."""
     d = d_model or cfg.d_model
+    d_in = d_in or d
     hd = cfg.resolved_head_dim
     hq, hkv = cfg.num_heads, cfg.num_kv_heads
     pre = (layers,) if layers else ()
     ax = ("layers",) if layers else ()
     out = {
-        "wq": ParamDef(pre + (d, hq * hd), ax + ("embed", "qkv")),
-        "wk": ParamDef(pre + (d, hkv * hd), ax + ("embed", "qkv")),
-        "wv": ParamDef(pre + (d, hkv * hd), ax + ("embed", "qkv")),
+        "wq": ParamDef(pre + (d_in, hq * hd), ax + ("embed", "qkv")),
+        "wk": ParamDef(pre + (d_in, hkv * hd), ax + ("embed", "qkv")),
+        "wv": ParamDef(pre + (d_in, hkv * hd), ax + ("embed", "qkv")),
         "wo": ParamDef(pre + (hq * hd, d), ax + ("qkv", "embed"),
                        scale=1.0 / max(1, 2 * cfg.num_layers) ** 0.5),
     }
@@ -194,6 +200,9 @@ def attention(p: Tree, x: jax.Array, cfg, *, positions: jax.Array,
     kernel reads the cache without a relayout.  With ``cache_layer`` cache
     holds every layer's [L, B, KV, hd, T]: this layer's tokens are written
     in place at that index, and the kernel reads the layer where it lies.
+    The softmax scale is ``cfg.attn_scale_mult / sqrt(hd)`` (the multiplier
+    goes into q, as every path below divides by sqrt(hd)); x's width may
+    differ from the output's (``attention_defs``' ``d_in``).
     """
     b, s, _ = x.shape
     hd = cfg.resolved_head_dim
@@ -216,6 +225,8 @@ def attention(p: Tree, x: jax.Array, cfg, *, positions: jax.Array,
         kpos = positions if cache is None else (
             cache_pos + jnp.arange(k.shape[1])[None, :])
         k = rope(k, kpos, cfg.rope_theta)
+    if cfg.attn_scale_mult != 1.0:
+        q = (q.astype(jnp.float32) * cfg.attn_scale_mult).astype(q.dtype)
 
     new_cache = None
     if cache is not None:
@@ -238,10 +249,9 @@ def attention(p: Tree, x: jax.Array, cfg, *, positions: jax.Array,
     if cache is not None and s == 1 and cfg.use_flash and memory is None:
         # single-token decode through the Pallas flash-decode kernel:
         # streams the cache through VMEM once, no HBM score traffic
-        from repro.kernels.flash_decode import flash_decode
         lens = jnp.full((b,), 0, jnp.int32) + (cache_pos + 1)
-        out = flash_decode(qg[:, 0], ck, cv, lens,
-                           cache_layer)[:, None]             # [B,1,KV,G,hd]
+        out = _flash_decode(qg[:, 0], ck, cv, lens,
+                            cache_layer)[:, None]            # [B,1,KV,G,hd]
     elif cache is not None:
         # attention directly in cache layout [B, KV, hd, T]: transposing
         # the full cache (moveaxis) would read+write it twice per step,
@@ -276,6 +286,35 @@ def attention(p: Tree, x: jax.Array, cfg, *, positions: jax.Array,
     return shard(y, "batch", "seq", "embed"), new_cache
 
 
+def _flash_decode(q, k_cache, v_cache, lengths, layer):
+    """The flash_decode kernel, on each chip's share of the KV heads when
+    the mesh's ``model`` axis is larger than 1: Mosaic kernels cannot be
+    partitioned automatically, so a ``shard_map`` over the cache's batch
+    and head axes (as the sharding rules place them) gives each chip its
+    own heads' rows of the cache, whole in head dim and length (the
+    rules ``launch.steps.rules_for`` gives a flash config).  A cache whose
+    head dim or length is sharded is refused: the kernel would need it
+    gathered whole on every chip, at every layer."""
+    from repro.kernels.flash_decode import flash_decode
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or dict(mesh.shape).get("model", 1) == 1:
+        return flash_decode(q, k_cache, v_cache, lengths, layer)
+    if any(resolve_spec(("cache_hd", "cache_seq"),
+                        dims=k_cache.shape[-2:])):
+        raise ValueError("flash_decode on a mesh needs the KV cache sharded "
+                         "over its heads only (rules_for with use_flash)")
+    b, kv = q.shape[:2]
+    bat, heads = resolve_spec(("cache_batch", "cache_heads"), dims=(b, kv))
+    q_spec = P(bat, heads, None, None)
+    c_spec = P(*([None] * (k_cache.ndim - 4)), bat, heads, None, None)
+    args, specs = (q, k_cache, v_cache, lengths), (q_spec, c_spec, c_spec,
+                                                   P(bat))
+    if layer is not None:
+        args, specs = args + (layer,), specs + (P(),)
+    return jax.shard_map(flash_decode, in_specs=specs, out_specs=q_spec,
+                         check_vma=False)(*args)
+
+
 # ---------------------------------------------------------------------------
 # MLP
 
@@ -295,10 +334,31 @@ def mlp_defs(cfg, gated: bool = True, layers: int = 0,
     return out
 
 
-def mlp(p: Tree, x: jax.Array) -> jax.Array:
+ACTS = {"silu": jax.nn.silu,
+        "gelu": functools.partial(jax.nn.gelu, approximate=False)}
+
+
+def adapter_defs(cfg, invocations: int) -> Tree:
+    """Per-invocation rank-r LoRA on a gated MLP's gate and up projections
+    (zamba2's shared-MLP adapters): x -> (x a) gate, (x a) up."""
+    d, f, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    pre, ax = (invocations,), ("layers",)
+    return {"a": ParamDef(pre + (d, r), ax + ("embed", "lora")),
+            "gate": ParamDef(pre + (r, f), ax + ("lora", "mlp")),
+            "up": ParamDef(pre + (r, f), ax + ("lora", "mlp"))}
+
+
+def mlp(p: Tree, x: jax.Array, act: str = "silu",
+        adapter: Optional[Tree] = None) -> jax.Array:
     up = x @ GW(p["w_up"])
     if "w_gate" in p:
-        h = jax.nn.silu(x @ GW(p["w_gate"])) * up
+        gate = x @ GW(p["w_gate"])
+        if adapter is not None:
+            with jax.named_scope("adapter"):
+                a = x @ adapter["a"]
+                gate = gate + a @ adapter["gate"]
+                up = up + a @ adapter["up"]
+        h = ACTS[act](gate) * up
     else:
         h = jax.nn.gelu(up)
     h = shard(h, "batch", "seq", "mlp")
@@ -391,6 +451,11 @@ def moe_ffn(p: Tree, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
 
 def embed_defs(cfg) -> Tree:
     d = cfg.d_model
+    if cfg.tie_embeddings:
+        # one table for both ends, vocab-sharded: the lookup gathers across
+        # shards, and the logits come out vocab-sharded
+        return {"tok": ParamDef((cfg.padded_vocab, d), ("vocab", "embed"),
+                                scale=1.0, fan_in=d)}
     return {
         # input table D-sharded (tiny per-device slice, gather stays local)
         "tok": ParamDef((cfg.padded_vocab, d), ("vocab_rep", "embed_shard"),
@@ -406,4 +471,5 @@ def embed(p: Tree, tokens: jax.Array) -> jax.Array:
 
 
 def unembed(p: Tree, x: jax.Array) -> jax.Array:
-    return shard(x @ GW(p["out"]), "batch", "seq", "vocab")
+    out = GW(p["out"]) if "out" in p else GW(p["tok"]).T
+    return shard(x @ out, "batch", "seq", "vocab")

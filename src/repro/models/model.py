@@ -7,9 +7,10 @@ Families and their block stacks:
   moe     (granite / llama4-maverick): groups of (period-1) dense layers + 1
           MoE layer, two-level scan.
   ssm     (rwkv6): scan over RWKV6 time-mix/channel-mix layers.
-  hybrid  (zamba2): scan over groups of Mamba2 layers, a single SHARED
-          attention+MLP block applied between groups (zamba-style weight
-          sharing — the shared block's weights are not stacked).
+  hybrid  (zamba2): one scan over the Mamba2 layers; before each layer of
+          ``hybrid_layer_ids`` one of ``shared_blocks`` SHARED transformer
+          blocks (used in turn; their weights are not stacked) runs, and its
+          output joins that layer's input (zamba-style weight sharing).
   vlm     (llama-3.2-vision): groups of self-attention layers with a
           cross-attention block (into stub image embeddings) per group.
   audio   (whisper): encoder scan (bidirectional) + decoder scan
@@ -32,6 +33,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import shard
@@ -107,7 +109,18 @@ class LM:
             defs["blocks"] = Ssm.rwkv_defs(cfg, L)
         elif fam == "hybrid":
             defs["blocks"] = Ssm.mamba_defs(cfg, L)
-            defs["shared_attn"] = self._dense_block_defs(layers=0)
+            defs["shared"] = {f"block{k}": self._shared_block_defs()
+                              for k in range(cfg.shared_blocks)}
+            n_inv = len(cfg.hybrid_layers)
+            own: Tree = {}
+            if cfg.adapter_rank:
+                own["adapter"] = Lyr.adapter_defs(cfg, n_inv)
+            if cfg.hybrid_linear:
+                d = cfg.d_model
+                own["linear"] = ParamDef((n_inv, d, d),
+                                         ("layers", "embed", "mlp"))
+            if own:
+                defs["hybrid"] = own
         elif fam == "audio":
             enc = cfg.encoder_layers or L
             defs["encoder"] = self._dense_block_defs(
@@ -153,6 +166,18 @@ class LM:
                                         (layers,) if layers else ())
             out["xattn"] = Lyr.attention_defs(cfg, layers=layers)
         return out
+
+    def _shared_block_defs(self) -> Tree:
+        """A zamba2 shared block: norm, attention over the block's input
+        (concat(hidden, embeddings) with ``shared_concat``), norm, gated
+        MLP; no residual of its own."""
+        cfg = self.cfg
+        d = cfg.d_model
+        d_in = d * (2 if cfg.shared_concat else 1)
+        return {"ln1": Lyr.norm_defs(d_in),
+                "attn": Lyr.attention_defs(cfg, d_in=d_in),
+                "ln2": Lyr.norm_defs(d),
+                "ffn": Lyr.mlp_defs(cfg)}
 
     def init(self, rng: jax.Array) -> Tree:
         return init_params(rng, self.param_defs())
@@ -245,7 +270,7 @@ class LM:
             x, _ = self._scan(_maybe_remat(body, cfg.remat),
                                 x, params["blocks"])
         elif fam == "hybrid":
-            x = self._hybrid_forward(params, x, positions, impl)
+            x, _ = self._hybrid_stack(params, x, positions, impl=impl)
         elif fam == "audio":
             x, aux_total = self._audio_forward(params, batch, x, positions,
                                                impl)
@@ -266,23 +291,84 @@ class LM:
         logits = Lyr.unembed(params["embed"], x)
         return logits, aux_total
 
-    def _hybrid_forward(self, params, x, positions, impl):
+    @jax.named_scope("shared_block")
+    def _shared_block(self, p: Tree, own: Tree, x, x0, positions, *, impl,
+                      cache=None, cache_pos=None, inv=None):
+        """One invocation ``inv`` of a shared block: its output, to be added
+        to the next Mamba layer's input, and the invocation's KV cache
+        (``cache`` holds every invocation's, written in place at ``inv``)."""
         cfg = self.cfg
-        k = cfg.shared_attn_every or cfg.num_layers
-        groups = cfg.num_layers // k
-        stacked = _stack_reshape(params["blocks"], groups, k)
-        shared = params["shared_attn"]
+        h = jnp.concatenate([x, x0], -1) if cfg.shared_concat else x
+        h = Lyr.apply_norm(p["ln1"], h, cfg.norm_eps)
+        a, kv = Lyr.attention(p["attn"], h, cfg, positions=positions,
+                              cache=cache, cache_pos=cache_pos,
+                              cache_layer=None if cache is None else inv,
+                              impl=impl)
+        h = Lyr.apply_norm(p["ln2"], a, cfg.norm_eps)
+        mine = jax.tree.map(
+            lambda w: jax.lax.dynamic_index_in_dim(w, inv, 0, False), own)
+        m = Lyr.mlp(p["ffn"], h, cfg.mlp_act, adapter=mine.get("adapter"))
+        if "linear" in mine:
+            m = shard(m @ mine["linear"], "batch", "seq", "embed")
+        return m, kv
 
-        def group(x, gp):
-            def inner(x, p):
-                y, _ = Ssm.mamba_block(p, x, cfg)
-                return y, None
-            x, _ = self._scan(_maybe_remat(inner, cfg.remat), x, gp)
-            y, _, _ = self._dense_block(shared, x, positions, impl=impl)
-            return y, None
+    def _hybrid_stack(self, params, x, positions, *, impl, cache=None,
+                      cache_pos=None, fresh=False):
+        """The zamba2 stack: one scan over the Mamba layers.  Each layer's
+        xs hold its index, the branch to run before it (0: none, k: shared
+        block k-1) and its invocation's index, which picks the adapter,
+        the linear and the KV cache of that invocation.  With a cache, the
+        stacked Mamba states and KV caches are the scan's carry, each
+        layer's written in place; a ``fresh`` (prefill) pass starts each
+        Mamba layer from a zero state, whatever the cache holds (attention
+        masks the cache's columns past the prompt)."""
+        cfg = self.cfg
+        L, nb = cfg.num_layers, cfg.shared_blocks
+        branch, inv = np.zeros(L, np.int32), np.zeros(L, np.int32)
+        for n, i in enumerate(cfg.hybrid_layers):
+            branch[i], inv[i] = 1 + n % nb, n
+        x0, own = x, params.get("hybrid", {})
 
-        x, _ = self._scan(group, x, stacked)
-        return x
+        def shared(k):
+            def run(x, kv, j):
+                return self._shared_block(params["shared"][f"block{k}"],
+                                          own, x, x0,
+                                          positions, impl=impl, cache=kv,
+                                          cache_pos=cache_pos, inv=j)
+            return run
+
+        def skip(x, kv, j):
+            return jnp.zeros_like(x), kv
+
+        def body(carry, xs):
+            (x, st), (p, i, br, j) = carry, xs
+            kv = None if st is None else st["shared"]
+            extra, kv = jax.lax.switch(
+                br, [skip] + [shared(k) for k in range(nb)], x, kv, j)
+            if st is None:
+                return (Ssm.mamba_block(p, x, cfg, extra=extra)[0], None), None
+            mine = None if fresh else jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, False),
+                st["mamba"])
+            y, mine = Ssm.mamba_block(p, x, cfg, state=mine, extra=extra)
+            mst = jax.tree.map(
+                lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                    a, u.astype(a.dtype), i, 0), st["mamba"], mine)
+            return (y, {"mamba": mst, "shared": kv}), None
+
+        if cache is None:
+            body = _maybe_remat(body, cfg.remat)
+        (x, cache), _ = self._scan(
+            body, (x, cache),
+            (params["blocks"], jnp.arange(L), jnp.asarray(branch),
+             jnp.asarray(inv)))
+        if cache is not None:
+            # handed back as the cache came in (the partitioner may split
+            # a replicated leaf otherwise, and the next step then refuses it)
+            cache = {**cache, "mamba": {
+                k: shard(a, *Ssm.STATE_AXES[k])
+                for k, a in cache["mamba"].items()}}
+        return x, cache
 
     def _moe_forward(self, params, x, positions, impl):
         cfg = self.cfg
@@ -399,9 +485,8 @@ class LM:
         if fam == "ssm":
             return Ssm.rwkv_state_defs(cfg, batch, L)
         if fam == "hybrid":
-            groups = L // (cfg.shared_attn_every or L)
             return {"mamba": Ssm.mamba_state_defs(cfg, batch, L),
-                    "shared": kv(groups, max_seq)}
+                    "shared": kv(len(cfg.hybrid_layers), max_seq)}
         if fam == "audio":
             return {"self": kv(L, max_seq),
                     "cross": kv(L, self.frames_len(max_seq, decode=True),
@@ -439,7 +524,7 @@ class LM:
         x = Lyr.embed(params["embed"], tokens)
         impl = self._impl(s)
         x, cache = self._stack_with_cache(params, batch, x, positions, cache,
-                                          cache_pos=0, impl=impl)
+                                          cache_pos=0, impl=impl, fresh=True)
         with jax.named_scope("head"):
             x = Lyr.apply_norm(params["final_norm"], x[:, -1:], cfg.norm_eps)
             logits = Lyr.unembed(params["embed"], x)
@@ -465,7 +550,7 @@ class LM:
 
     @jax.named_scope("layers")
     def _stack_with_cache(self, params, batch, x, positions, cache,
-                          cache_pos, impl):
+                          cache_pos, impl, fresh=False):
         """The layer loop over the cache: the scans with the cache's
         stacking and reshaping around them, all under the ``layers``
         scope.  Self-attention caches are [L, B, KV, hd, T] (T last: see
@@ -487,29 +572,9 @@ class LM:
             return x, new_state
 
         if fam == "hybrid":
-            k = cfg.shared_attn_every or cfg.num_layers
-            groups = cfg.num_layers // k
-            stacked = _stack_reshape(params["blocks"], groups, k)
-            mstate = _stack_reshape(cache["mamba"], groups, k)
-            shared = params["shared_attn"]
-
-            def group(x, xs):
-                gp, gst, skv = xs
-
-                def inner(x, pst):
-                    p, st = pst
-                    y, st2 = Ssm.mamba_block(p, x, cfg, state=st)
-                    return y, st2
-                x, st2 = self._scan(inner, x, (gp, gst))
-                y, kv2, _ = self._dense_block(shared, x, positions, impl=impl,
-                                              cache=skv, cache_pos=cache_pos)
-                return y, (st2, kv2)
-
-            x, (mst2, skv2) = self._scan(
-                group, x, (stacked, mstate, cache["shared"]))
-            new_m = jax.tree.map(
-                lambda a: a.reshape((groups * k,) + a.shape[2:]), mst2)
-            return x, {"mamba": new_m, "shared": skv2}
+            return self._hybrid_stack(params, x, positions, impl=impl,
+                                      cache=cache, cache_pos=cache_pos,
+                                      fresh=fresh)
 
         if fam == "vlm":
             kk = cfg.cross_attn_every

@@ -9,7 +9,8 @@ pairwise-decay tensor.  All pairwise exponents are of the form
 
 State shapes (per layer, carried through decode):
   RWKV6  : [B, nh, hd, hd]   (key-dim x value-dim outer-product state)
-  Mamba2 : [B, nh, hd, st]   (head-dim x ssm-state outer-product state)
+  Mamba2 : [B, nh, hd, st]   (head-dim x ssm-state outer-product state;
+           kept flat as [B, nh, hd*st] in the serve cache)
 """
 
 from __future__ import annotations
@@ -213,8 +214,13 @@ def rwkv_state_defs(cfg, batch: int, layers: int) -> Tree:
 
 
 def mamba_defs(cfg, layers: int) -> Tree:
-    d, di, st = cfg.d_model, cfg.d_inner, cfg.ssm_state
-    nh = cfg.ssm_heads
+    """One Mamba2 layer per stacked row.  The in-projection is separate z,
+    x, B/C and dt weights, so tensor parallelism splits heads: z and x
+    over ``ssm_inner`` (whole heads of ``ssm_head_dim``), dt and the
+    per-head vectors over ``ssm_heads``; B and C (``ssm_groups`` groups of
+    ``ssm_state``) are replicated."""
+    d, di, nh = cfg.d_model, cfg.d_inner, cfg.ssm_heads
+    bc = 2 * cfg.ssm_groups * cfg.ssm_state
     wconv = cfg.ssm_conv_width
     L = (layers,)
     ax = ("layers",)
@@ -224,10 +230,15 @@ def mamba_defs(cfg, layers: int) -> Tree:
 
     return {
         "ln": {"scale": w((d,), ("embed",), init="ones")},
-        # in_proj -> [z (di), x (di), B (st), C (st), dt (nh)]
-        "w_in": w((d, 2 * di + 2 * st + nh), ("embed", "ssm_inner")),
-        "conv_w": w((wconv, di + 2 * st), ("conv", "ssm_inner"), fan_in=wconv),
-        "conv_b": w((di + 2 * st,), ("ssm_inner",), init="zeros"),
+        "w_z": w((d, di), ("embed", "ssm_inner")),
+        "w_x": w((d, di), ("embed", "ssm_inner")),
+        "w_bc": w((d, bc), ("embed", None)),          # [B | C]
+        "w_dt": w((d, nh), ("embed", "ssm_heads")),
+        # depthwise causal conv over x and over B/C, with a bias
+        "conv_x": w((wconv, di), ("conv", "ssm_inner"), fan_in=wconv),
+        "conv_x_b": w((di,), ("ssm_inner",), init="zeros"),
+        "conv_bc": w((wconv, bc), ("conv", None), fan_in=wconv),
+        "conv_bc_b": w((bc,), (None,), init="zeros"),
         "a_log": w((nh,), ("ssm_heads",), init="const", scale=0.5),
         "dt_bias": w((nh,), ("ssm_heads",), init="zeros"),
         "d_skip": w((nh,), ("ssm_heads",), init="ones"),
@@ -239,7 +250,7 @@ def mamba_defs(cfg, layers: int) -> Tree:
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
                  buf: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
-    """Depthwise causal conv along time.  x: [B, T, C]; w: [W, C].
+    """Depthwise causal conv along time, then silu.  x: [B, T, C]; w: [W, C].
     buf: [B, W-1, C] history for decode (None -> zero history)."""
     wlen = w.shape[0]
     hist = buf if buf is not None else \
@@ -250,41 +261,41 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
 
 
 def mamba_ssd_chunked(xh, B, C, logA, state, chunk: int):
-    """SSD scan.  xh: [B,T,nh,hd] (dt-scaled inputs), B/C: [B,T,st],
-    logA: [B,T,nh] (log decay <= 0), state: [B,nh,hd,st]."""
+    """SSD scan.  xh: [B,T,nh,hd] (dt-scaled inputs), B/C: [B,T,nh,st]
+    (each head's group's), logA: [B,T,nh] (log decay <= 0), state:
+    [B,nh,hd,st]."""
     b, t, nh, hd = xh.shape
     st = B.shape[-1]
     c = min(chunk, t)
     tp = -(-t // c) * c
     if tp != t:
         # identity padding: x=B=C=0 contribute nothing, logA=0 is decay 1
-        xh = jnp.pad(xh, ((0, 0), (0, tp - t), (0, 0), (0, 0)))
-        B = jnp.pad(B, ((0, 0), (0, tp - t), (0, 0)))
-        C = jnp.pad(C, ((0, 0), (0, tp - t), (0, 0)))
+        pad = ((0, 0), (0, tp - t), (0, 0), (0, 0))
+        xh, B, C = (jnp.pad(a, pad) for a in (xh, B, C))
         logA = jnp.pad(logA, ((0, 0), (0, tp - t), (0, 0)))
     n = tp // c
 
     xs = jnp.moveaxis(xh.reshape(b, n, c, nh, hd), 1, 0)
-    Bs = jnp.moveaxis(B.reshape(b, n, c, st), 1, 0)
-    Cs = jnp.moveaxis(C.reshape(b, n, c, st), 1, 0)
+    Bs = jnp.moveaxis(B.reshape(b, n, c, nh, st), 1, 0)
+    Cs = jnp.moveaxis(C.reshape(b, n, c, nh, st), 1, 0)
     As = jnp.moveaxis(logA.reshape(b, n, c, nh), 1, 0)
 
     def step(state, inp):
         xx, bb, cc, aa = (i.astype(jnp.float32) for i in inp)
         logA_c = jnp.cumsum(aa, axis=1)               # [B,c,nh] inclusive
         # inter: y_t += exp(logA_t) * C_t . state
-        inter = jnp.einsum("bts,bnds,btn->btnd", cc, state,
+        inter = jnp.einsum("btns,bnds,btn->btnd", cc, state,
                            jnp.exp(logA_c))
         # intra (i <= t): dec[t,i] = exp(logA_t - logA_i)
         diff = logA_c[:, :, None] - logA_c[:, None, :, :]   # [B,c,c,nh]
         mask = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]
         dec = jnp.exp(jnp.minimum(diff, 0.0)) * mask[None, :, :, None]
-        scores = jnp.einsum("bts,bis->bti", cc, bb)[:, :, :, None] * dec
+        scores = jnp.einsum("btns,bins->btin", cc, bb) * dec
         intra = jnp.einsum("btin,bind->btnd", scores, xx)
         # state update
         x_dec = xx * jnp.exp(logA_c[:, -1:, :] - logA_c)[..., None]
         new_state = state * jnp.exp(logA_c[:, -1])[..., None, None] + \
-            jnp.einsum("bind,bis->bnds", x_dec, bb)
+            jnp.einsum("bind,bins->bnds", x_dec, bb)
         return new_state, (inter + intra)
 
     state, out = jax.lax.scan(step, state.astype(jnp.float32),
@@ -292,57 +303,89 @@ def mamba_ssd_chunked(xh, B, C, logA, state, chunk: int):
     return jnp.moveaxis(out, 0, 1).reshape(b, tp, nh, hd)[:, :t], state
 
 
-def mamba_block(p: Tree, x: jax.Array, cfg,
-                state: Optional[Tree] = None) -> Tuple[jax.Array, Optional[Tree]]:
-    """One Mamba2 layer.  state: {"ssm": [B,nh,hd,st], "conv": [B,W-1,ch]}."""
+def gated_group_rms_norm(y: jax.Array, z: jax.Array, scale: jax.Array,
+                         groups: int, eps: float, dtype) -> jax.Array:
+    """Mamba2's gated RMSNorm with the gate before the norm
+    (``norm_before_gate=False``): y * silu(z), normalised over each group's
+    heads.  y, z: [B, T, nh, hd] -> [B, T, nh*hd] in ``dtype``, times
+    ``scale``.  The sum of squares is taken per head, then summed over the
+    group: with heads split over chips, a group's sum is reduced across
+    the chips that hold it."""
+    b, t, nh, hd = y.shape
+    yg = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    ssq = jnp.sum(yg * yg, axis=-1)                           # [B,T,nh]
+    var = jnp.mean(ssq.reshape(b, t, groups, nh // groups), -1) / hd
+    rstd = jnp.repeat(jax.lax.rsqrt(var + eps), nh // groups, axis=-1)
+    return (yg * rstd[..., None]).astype(dtype).reshape(b, t, nh * hd) * scale
+
+
+@jax.named_scope("mamba")
+def mamba_block(p: Tree, x: jax.Array, cfg, state: Optional[Tree] = None,
+                extra: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, Optional[Tree]]:
+    """One Mamba2 layer with its residual: x + mixer(norm(x + extra)).
+    ``extra`` (a hybrid's shared-block output) joins the layer's input but
+    not its residual.  Returns the output and the state after the last
+    token; ``state`` is the state before the first (None: zeros), as
+    {"ssm": [B,nh,hd*st], "conv_x": [B,W-1,di], "conv_bc": [B,W-1,
+    2*groups*st]}: the recurrent state flat in its last dim, which a TPU
+    lays out lane-dense (a minor dim of 64 would be padded to 128)."""
     b, t, d = x.shape
-    di, stt, nh = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
+    stt, nh, g = cfg.ssm_state, cfg.ssm_heads, cfg.ssm_groups
     hd = cfg.ssm_head_dim
     decode = state is not None
 
-    xn = rms_norm(x, p["ln"]["scale"], cfg.norm_eps)
-    proj = xn @ p["w_in"]
-    proj = shard(proj, "batch", "seq", "ssm_inner")
-    z, xin, Bc, Cc, dt = jnp.split(
-        proj, [di, 2 * di, 2 * di + stt, 2 * di + 2 * stt], axis=-1)
+    xn = rms_norm(x if extra is None else x + extra, p["ln"]["scale"],
+                  cfg.norm_eps)
+    z = shard(xn @ p["w_z"], "batch", "seq", "ssm_inner")
+    xin = shard(xn @ p["w_x"], "batch", "seq", "ssm_inner")
+    dt = shard(xn @ p["w_dt"], "batch", "seq", "ssm_heads")
+    xin, conv_x = _causal_conv(xin, p["conv_x"], p["conv_x_b"],
+                               state["conv_x"] if decode else None)
+    bc, conv_bc = _causal_conv(xn @ p["w_bc"], p["conv_bc"], p["conv_bc_b"],
+                               state["conv_bc"] if decode else None)
 
-    conv_in = jnp.concatenate([xin, Bc, Cc], axis=-1)
-    conv_out, conv_buf = _causal_conv(
-        conv_in, p["conv_w"], p["conv_b"],
-        state["conv"] if decode else None)
-    xin, Bc, Cc = jnp.split(conv_out, [di, di + stt], axis=-1)
+    def per_head(a):                         # [B,T,g*st] -> [B,T,nh,st]
+        a = jnp.repeat(a.reshape(b, t, g, stt), nh // g, axis=2)
+        return shard(a, "batch", "seq", "ssm_heads", None)
+    Bh, Ch = (per_head(a) for a in jnp.split(bc, 2, axis=-1))
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])   # [B,T,nh]
     logA = -jnp.exp(p["a_log"].astype(jnp.float32))[None, None] * dt
     xh = xin.reshape(b, t, nh, hd)
     xh_dt = xh.astype(jnp.float32) * dt[..., None]
 
-    ssm0 = state["ssm"] if decode else jnp.zeros((b, nh, hd, stt), jnp.float32)
-    y, ssm = mamba_ssd_chunked(xh_dt, Bc, Cc, logA, ssm0,
-                               min(cfg.chunk_size, t))
+    ssm0 = (state["ssm"].reshape(b, nh, hd, stt) if decode
+            else jnp.zeros((b, nh, hd, stt), jnp.float32))
+    with jax.named_scope("ssd"):
+        y, ssm = mamba_ssd_chunked(xh_dt, Bh, Ch, logA, ssm0,
+                                   min(cfg.chunk_size, t))
     y = y + p["d_skip"].astype(jnp.float32)[None, None, :, None] * \
         xh.astype(jnp.float32)
-    y = y.reshape(b, t, di).astype(x.dtype)
-    y = rms_norm(y, p["norm"]["scale"], cfg.norm_eps) * jax.nn.silu(z)
+    y = gated_group_rms_norm(y, z.reshape(b, t, nh, hd), p["norm"]["scale"],
+                             g, cfg.norm_eps, x.dtype)
     y = shard(y, "batch", "seq", "ssm_inner")
     out = x + y @ p["w_out"]
     out = shard(out, "batch", "seq", "embed")
 
-    new_state = None
-    if decode:
-        new_state = {"ssm": ssm, "conv": conv_buf}
-    return out, new_state
+    return out, {"ssm": ssm.reshape(b, nh, hd * stt), "conv_x": conv_x,
+                 "conv_bc": conv_bc}
+
+
+#: logical axes of the stacked Mamba2 serve state
+STATE_AXES = {"ssm": ("layers", "cache_batch", "ssm_heads", None),
+              "conv_x": ("layers", "cache_batch", None, "ssm_inner"),
+              "conv_bc": ("layers", "cache_batch", None, None)}
 
 
 def mamba_state_defs(cfg, batch: int, layers: int) -> Tree:
     di, stt, nh = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
     hd = cfg.ssm_head_dim
-    wconv = cfg.ssm_conv_width
-    return {
-        "ssm": ParamDef((layers, batch, nh, hd, stt),
-                        ("layers", "cache_batch", "ssm_heads", None, None),
-                        dtype=jnp.float32, init="zeros"),
-        "conv": ParamDef((layers, batch, wconv - 1, di + 2 * stt),
-                         ("layers", "cache_batch", None, "ssm_inner"),
-                         init="zeros"),
-    }
+    w = cfg.ssm_conv_width - 1
+    bc = 2 * cfg.ssm_groups * stt
+    shapes = {"ssm": (layers, batch, nh, hd * stt),
+              "conv_x": (layers, batch, w, di),
+              "conv_bc": (layers, batch, w, bc)}
+    return {k: ParamDef(shapes[k], STATE_AXES[k], init="zeros",
+                        dtype=jnp.float32 if k == "ssm" else jnp.bfloat16)
+            for k in shapes}

@@ -1,6 +1,6 @@
 """Optional-``hypothesis`` shim for the test suite.
 
-When hypothesis is installed the real ``given``/``settings``/``st`` are
+When hypothesis is installed the real ``given``/``example``/``settings``/``st`` are
 re-exported unchanged.  When it is absent, property tests decorated with the
 fallback ``given`` skip gracefully at call time, and the fallback ``st``
 accepts any strategy-construction expression at module import time — so the
@@ -10,7 +10,7 @@ rest of the suite (compiler integration, unit tests) still collects and runs.
 from __future__ import annotations
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:                      # pragma: no cover - env dependent
     import pytest
@@ -21,6 +21,8 @@ except ImportError:                      # pragma: no cover - env dependent
         def deco(fn):
             return fn
         return deco
+
+    example = settings
 
     def given(*args, **kwargs):
         def deco(fn):

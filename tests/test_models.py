@@ -145,8 +145,9 @@ def test_mamba_chunked_matches_stepwise():
     rng = np.random.default_rng(1)
     b, t, nh, hd, st = 2, 24, 2, 8, 4
     xh = jnp.asarray(rng.normal(size=(b, t, nh, hd)).astype("float32"))
-    B = jnp.asarray(rng.normal(size=(b, t, st)).astype("float32"))
-    C = jnp.asarray(rng.normal(size=(b, t, st)).astype("float32"))
+    # B and C per head (each head's group's)
+    B = jnp.asarray(rng.normal(size=(b, t, nh, st)).astype("float32"))
+    C = jnp.asarray(rng.normal(size=(b, t, nh, st)).astype("float32"))
     logA = -jnp.asarray(rng.uniform(0.05, 1.0, size=(b, t, nh))
                         .astype("float32"))
     s0 = jnp.zeros((b, nh, hd, st), jnp.float32)
@@ -228,7 +229,8 @@ def test_flash_decode_kernel_in_model_decode(arch, edits):
 
 
 def test_cell_runnability_covers_40():
-    """40 assigned cells: count runnable + documented skips."""
+    """44 cells (the 40 assigned, and zamba2-7b's 4): count runnable +
+    documented skips."""
     total = runnable = 0
     for arch, cfg in ARCHS.items():
         for shape in SHAPES.values():
@@ -238,5 +240,5 @@ def test_cell_runnability_covers_40():
                 runnable += 1
             else:
                 assert "long_500k" in why or "sub-quadratic" in why
-    assert total == 40
-    assert runnable == 32          # 8 documented long_500k skips
+    assert total == 44
+    assert runnable == 36          # 8 documented long_500k skips

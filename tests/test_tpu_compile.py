@@ -193,3 +193,81 @@ def test_granite_decode_step_moves_no_cache(one_chip, monkeypatch):
                                           header)}
     assert cache_params <= aliased, header[:300]
     assert compiled.memory_analysis().temp_size_in_bytes < layer_elems * 2
+
+
+def test_flash_decode_compiles_for_v5e_at_zamba2_widths(one_chip):
+    """One chip's share of zamba2-7b's decode: 8 KV heads of 224, one
+    query head each, a 2048-long stacked cache."""
+    from repro.kernels.flash_decode import flash_decode
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q = sds((32, 8, 1, 224), jnp.bfloat16)
+    cache = sds((13, 32, 8, 224, 2048), jnp.bfloat16)
+    compiled = flash_decode.lower(q, cache, cache, sds((32,), jnp.int32),
+                                  sds((), jnp.int32),
+                                  interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """The described v5e:2x2 host as the serve path's (data, model) mesh."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import AxisType, Mesh
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
+def test_zamba2_decode_step_on_four_chips(four_chips, monkeypatch):
+    """zamba2-7b's decode step at its widths (12 layers: both shared
+    blocks), 4-way tensor parallel on a described v5e:2x2 host, batch 32
+    over a 2048-long cache donated: flash_decode runs as Mosaic kernels
+    under shard_map, the stacked KV caches and Mamba states alias the
+    outputs with nothing of cache size copied, and the step all-reduces."""
+    import importlib
+    import re
+    from repro.configs import ShapeSpec, get_config
+    from repro.distributed import sharding as shd
+    from repro.launch import steps as S
+    from repro.models import LM
+    fd = importlib.import_module("repro.kernels.flash_decode.flash_decode")
+    monkeypatch.setattr(fd, "resolve_interpret", lambda i: False)
+    fd.flash_decode.clear_cache()
+    b, t = 32, 2048
+    cfg = get_config("zamba2-7b").replace(num_layers=12, use_flash=True)
+    model = LM(cfg)
+    mesh = four_chips
+    prev = shd.get_rules()
+    shd.set_rules(S.rules_for(cfg))
+    try:
+        with jax.set_mesh(mesh):
+            p_sh, _, c_sh = S.serve_shardings(
+                model, mesh, ShapeSpec("serve", t, b, "decode"))
+            on = lambda tree, sh: jax.tree.map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                  sharding=s), tree, sh)
+            rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+            compiled = jax.jit(model.decode_step, donate_argnums=(2,)).lower(
+                on(model.shapes(), p_sh),
+                {"tokens": jax.ShapeDtypeStruct((b, 1), jnp.int32,
+                                                sharding=rep)},
+                on(model.cache_shapes(b, t), c_sh),
+                jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)).compile()
+    finally:
+        shd.set_rules(prev)
+        fd.flash_decode.clear_cache()
+    text = compiled.as_text()
+    kernels = {line.split("=", 1)[0].strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line}
+    assert kernels and all(k.startswith("%flash_decode") for k in kernels)
+    assert re.search(r"all-reduce(-start)?\(", text)
+    header = text.splitlines()[0]
+    assert len(re.findall(r"may-alias", header)) == 5     # k, v, 3 states
+    # per chip, the smallest stacked leaf: the f32 states 12 x 32 x 28 x
+    # 4096 (a copy of any cache leaf would pass it)
+    ssm = 12 * b * 28 * 4096 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < ssm
+    assert not [line for line in text.splitlines()
+                if re.search(r"= f32\[12,32,28,4096\]\S* copy\(", line)]
