@@ -223,6 +223,60 @@ def test_flash_decode_stacked_cache_equals_layer_slice(t, bk, frontier):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("t,bk", [(512, 256), (512, 128), (300, 128),
+                                  (640, None)])
+def test_flash_decode_ignores_the_cache_past_the_frontier(t, bk, stacked):
+    """Every cache column at or past a row's frontier (the live block's
+    tail, whole blocks past it, a partial last block's) holds NaN: the
+    output equals the oracle's on the clean cache, for rows of different
+    lengths, one of 1 and one on a block boundary."""
+    from repro.kernels.flash_decode import flash_decode, flash_decode_ref
+    rng = np.random.default_rng(t + (bk or 0) + stacked)
+    n_layers, b, kv, g, hd, layer = 3, 4, 2, 4, 64, 1
+    step = bk or 128
+    lens = np.array([1, step, step + 37, t], np.int32)
+    q = jnp.asarray(rng.normal(size=(b, kv, g, hd)).astype("float32"))
+    k, v = (rng.normal(size=(n_layers, b, kv, hd, t)).astype("float32")
+            for _ in range(2))
+    past = np.arange(t)[None, :] >= lens[:, None]            # [B, T]
+    past = np.broadcast_to(past[None, :, None, None, :], k.shape)
+    dirty_k, dirty_v = np.where(past, np.nan, k), np.where(past, np.nan, v)
+    want = flash_decode_ref(q, jnp.asarray(k[layer]), jnp.asarray(v[layer]),
+                            jnp.asarray(lens))
+    if stacked:
+        got = flash_decode(q, jnp.asarray(dirty_k), jnp.asarray(dirty_v),
+                           jnp.asarray(lens), jnp.int32(layer), bk=bk)
+    else:
+        got = flash_decode(q, jnp.asarray(dirty_k[layer]),
+                           jnp.asarray(dirty_v[layer]), jnp.asarray(lens),
+                           bk=bk)
+    assert np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("kv,g,hd,t,want_bk", [
+    (8, 2, 64, 1536, 512),            # granite-moe-1b-a400m decode
+    (8, 1, 224, 2048, None),          # zamba2-7b, one chip's 8 KV heads
+    (8, 2, 64, 4096, None),           # granite prefill's longest bucket
+    (8, 2, 64, 1056, None),           # ... its shortest, a partial block
+    (2, 4, 64, 100, 100)])            # shorter than a block: T itself
+def test_flash_decode_plan_fits_vmem(kv, g, hd, t, want_bk):
+    """decode_plan takes the largest block of 128 x 2^j columns (or T)
+    within the VMEM budget, and tiles T with kv_steps blocks."""
+    from repro.kernels.flash_decode.flash_decode import (VMEM_BUDGET,
+                                                         decode_plan,
+                                                         step_vmem_bytes)
+    bk, steps = decode_plan(kv, g, hd, t, 2)
+    assert steps == -(-t // bk)
+    assert step_vmem_bytes(kv, g, hd, bk, 2) <= VMEM_BUDGET
+    if want_bk is not None:
+        assert bk == want_bk
+    if bk < t:
+        assert bk % 128 == 0
+        assert step_vmem_bytes(kv, g, hd, 2 * bk, 2) > VMEM_BUDGET
+
+
 def test_blockwise_matches_flash_and_ref():
     """The model's jnp blockwise attention is a third implementation of the
     same math — all three must agree."""

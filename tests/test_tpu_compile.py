@@ -151,6 +151,18 @@ def test_granite_decode_step_keeps_flash_decode_kernel_name(one_chip,
     assert kernels and all(k.startswith("%flash_decode") for k in kernels)
 
 
+def test_granite_prefill_decode_shape_compiles_for_v5e(one_chip,
+                                                       monkeypatch):
+    """A decode step of granite.prefill's shortest bucket (B 16, a
+    1056-long cache: the plan's last block is partial) compiles with the
+    Mosaic kernel, one ``%flash_decode`` custom call in the layer scan."""
+    text = _granite_decode_step(one_chip, monkeypatch, layers=2, b=16,
+                                s=1056).as_text()
+    kernels = [line.split("=", 1)[0].strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and kernels[0].startswith("%flash_decode")
+
+
 #: ops that may hold a cache-sized value without moving the cache
 _CACHE_SIZED_OK = {"parameter", "bitcast", "get-tuple-element", "tuple",
                    "while", "dynamic-update-slice"}
