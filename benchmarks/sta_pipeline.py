@@ -1,21 +1,21 @@
-"""Vectorized STA / incremental re-timing benchmark: scalar vs numpy vs jax.
+"""Vectorized STA / incremental re-timing benchmark: scalar vs numpy.
 
 Times the post-PnR register-insertion loop (paper Section V-D) — the
 inner loop of every power-cap and Pareto-frontier sweep — under each
 ``sta_backend``, on the routed benchmark designs.  The contract is
 *asserted*, not just printed:
 
-* every engine's one-shot STA report is bit-identical to the scalar
+* the numpy engine's one-shot STA report is bit-identical to the scalar
   oracle (critical path ns, reconstruction, arrival maps, segments);
-* the pipelining loop is byte-identical across engines (same histories,
+* the pipelining loop is byte-identical across the two engines (same histories,
   stop reasons, register placements);
 * the numpy incremental engine reaches >= 5x warm speedup over the
   scalar loop on the headline app (harris x4).
 
 Timing protocol: the routed design and the lowering are built *outside*
 the timer (the lowering is structure-only, so one serves every run); a
-throwaway warm run per backend absorbs one-time costs (jax pays its XLA
-compile there); the reported number is the best of three timed runs of
+throwaway warm run per backend absorbs one-time costs; the reported
+number is the best of three timed runs of
 the full loop on a fresh deepcopy.
 
 The end-to-end section sweeps a small Pareto grid through
@@ -105,29 +105,25 @@ def bench_pipelining(fast: bool = False) -> List[Dict]:
                             lower_design)
 
     compiler = CascadeCompiler(cache=CompileCache())
-    backends = ("numpy", "jax")
 
     rows: List[Dict] = []
     for app, mult in (FAST_APPS if fast else BENCH_APPS):
         name = f"{app}x{mult}"
         design, tm = _routed(compiler, app, mult)
-        ref = analyze(design, tm)
-        for b in backends:                # one-shot bit-identity gate
-            _assert_reports_identical(name, ref,
-                                      analyze(design, tm, backend=b))
+        # one-shot bit-identity gate
+        _assert_reports_identical(name, analyze(design, tm),
+                                  analyze(design, tm, backend="numpy"))
         lowering = lower_design(design, tm)
         t_scalar, s_scalar = _time_loop(design, tm, "scalar")
-        row: Dict = {"app": name,
+        t_vec, s_vec = _time_loop(design, tm, "numpy", lowering=lowering)
+        assert s_vec == s_scalar, \
+            f"{name}: numpy loop diverged from the scalar loop"
+        rows.append({"app": name,
                      "routes": len(design.routes),
                      "rounds": len(s_scalar[0]),
-                     "scalar_s": round(t_scalar, 4)}
-        for b in backends:
-            t_vec, s_vec = _time_loop(design, tm, b, lowering=lowering)
-            assert s_vec == s_scalar, \
-                f"{name}: {b} loop diverged from the scalar loop"
-            row[f"{b}_s"] = round(t_vec, 4)
-            row[f"{b}_speedup"] = round(t_scalar / t_vec, 2)
-        rows.append(row)
+                     "scalar_s": round(t_scalar, 4),
+                     "numpy_s": round(t_vec, 4),
+                     "numpy_speedup": round(t_scalar / t_vec, 2)})
     print_csv(rows, "post-PnR pipelining loop, scalar vs vectorized STA "
                     "(wall seconds, best of %d)" % REPEATS)
     return rows

@@ -25,8 +25,7 @@ from .config import (PNR_BACKENDS, SIM_BACKENDS, STA_BACKENDS, cache_dir,
                      env_flag, env_float, env_int, force_host_device_count,
                      host_device_count, place_debug, pnr_backend,
                      sched_latency_weight, service_batch_window_s,
-                     service_max_batch, sim_backend, sta_backend,
-                     worker_count)
+                     service_max_batch, worker_count)
 from .dfg import DFG
 from .explore import (ExploreSpec, FrontierPoint, ParetoFrontier,
                       evaluate_candidate, explore_frontier, pareto_prune)
@@ -93,8 +92,7 @@ __all__ = [
     "cache_dir", "default_power_cap_mw", "disk_cache_enabled", "env_flag",
     "env_float", "env_int", "place_debug", "worker_count",
     "service_batch_window_s", "service_max_batch", "sched_latency_weight",
-    "PNR_BACKENDS", "pnr_backend", "SIM_BACKENDS", "sim_backend",
-    "STA_BACKENDS", "sta_backend",
+    "PNR_BACKENDS", "pnr_backend", "SIM_BACKENDS", "STA_BACKENDS",
     "host_device_count", "force_host_device_count", "devices",
     "CompileContext", "Pass", "PassPipeline", "PASS_REGISTRY",
     "DEFAULT_SCHEDULE", "POWER_CAPPED_SCHEDULE", "EXPLORE_SCHEDULE",

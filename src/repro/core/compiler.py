@@ -84,10 +84,9 @@ class PassConfig:
     #: size-adaptive default); ignored by the scalar/numpy backends.
     pnr_replicas: int = 0
     #: Timing-engine backend (``repro.core.config.STA_BACKENDS``:
-    #: ``"scalar"`` / ``"numpy"`` / ``"jax"``).  Drivers copy
-    #: ``CASCADE_STA_BACKEND`` here.  All backends are bit-identical
-    #: (see :mod:`repro.core.sta_vec`); it is a ``pipelined``-stage knob,
-    #: so routed-prefix stage artifacts are shared across backends.
+    #: ``"scalar"`` / ``"numpy"``).  Both are bit-identical (see
+    #: :mod:`repro.core.sta_vec`); it is a ``pipelined``-stage knob, so
+    #: routed-prefix stage artifacts are shared across backends.
     sta_backend: str = "scalar"
     #: Power budget (mW) for the ``power_capped_pipeline`` pass; ``None``
     #: means unconstrained (byte-identical to the plain post-PnR pass).
@@ -244,7 +243,7 @@ def _uses_device(cfg: "PassConfig") -> bool:
     """Whether ``cfg`` runs a kernel through jax.  Such a compile stays in
     the process that owns the accelerator: a pool worker would try to
     open the device a second time."""
-    return "jax" in (cfg.pnr_backend, cfg.sta_backend)
+    return cfg.pnr_backend == "jax"
 
 
 def _host_only_worker() -> None:
@@ -688,10 +687,10 @@ class CascadeCompiler:
         * ``"auto"`` (default) — ``"process"`` when more than one job
           misses every cache tier, else ``"thread"``.
 
-        A job whose config runs a jax kernel (``pnr_backend`` or
-        ``sta_backend`` ``"jax"``) always compiles on the thread path, in
-        this process: only one process may hold the accelerator.  Process
-        workers run with ``JAX_PLATFORMS=cpu``.
+        A job whose config runs a jax kernel (``pnr_backend`` ``"jax"``)
+        always compiles on the thread path, in this process: only one
+        process may hold the accelerator.  Process workers run with
+        ``JAX_PLATFORMS=cpu``.
 
         Jobs whose config schedules the ``pareto_frontier`` pass with more
         than one sweep point are *fanned out*: the shared prefix compiles

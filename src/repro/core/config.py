@@ -22,24 +22,11 @@ knob lives here and is re-exported from :mod:`repro.core`:
                          CLIs) and written into the ``PassConfig`` they
                          build — never read inside the compiler itself, so
                          the compile-cache key always reflects the cap.
-    CASCADE_SIM_BACKEND  default simulator backend for benchmark/driver
-                         CLIs: "interpreter", "numpy", or "jax"
-                         (``repro.core.sim_vec``).  Driver-side only —
-                         drivers pass it as the explicit ``backend=``
-                         argument; library code never reads it.
     CASCADE_PNR_BACKEND  default place-and-route kernel backend for the
                          benchmark/driver CLIs: "scalar", "numpy", or
                          "jax".  Driver-side only, like the power cap —
                          drivers copy it into ``PassConfig.pnr_backend``,
                          the compiler never reads it implicitly.
-    CASCADE_STA_BACKEND  default timing-analysis backend for the
-                         benchmark/driver CLIs: "scalar" (the oracle in
-                         ``repro.core.sta``), "numpy", or "jax" (the
-                         vectorized engine in ``repro.core.sta_vec``,
-                         bit-identical to the oracle).  Driver-side
-                         only — drivers copy it into
-                         ``PassConfig.sta_backend``; the library never
-                         reads it implicitly.
     CASCADE_SERVICE_BATCH_WINDOW_MS
                          how long the compile service's dispatcher holds
                          the queue open after the first request of a
@@ -210,57 +197,12 @@ PNR_BACKENDS = ("scalar", "numpy", "jax")
 SIM_BACKENDS = ("interpreter", "numpy", "jax")
 
 
-def sim_backend(default: str = "interpreter") -> str:
-    """Default simulator backend (``CASCADE_SIM_BACKEND``).
-
-    Driver-side only, exactly like :func:`pnr_backend`: benchmark CLIs
-    and the traffic-replay harness pass the value into the ``backend=``
-    argument of :func:`repro.core.sim.simulate` /
-    :func:`~repro.core.sim.simulate_sparse` — library code never reads
-    the env var implicitly, so oracle checks stay reproducible.  An
-    unknown value warns and falls back to ``default``.
-    """
-    v = os.environ.get("CASCADE_SIM_BACKEND")
-    if v is None or not v.strip():
-        return default
-    v = v.strip().lower()
-    if v not in SIM_BACKENDS:
-        warnings.warn(
-            f"ignoring unknown CASCADE_SIM_BACKEND={v!r} "
-            f"(expected one of {SIM_BACKENDS}); falling back to "
-            f"{default!r}", UserWarning, stacklevel=2)
-        return default
-    return v
-
-
 #: The application-STA backends (``PassConfig.sta_backend`` / the
 #: ``backend=`` argument of :func:`repro.core.sta.analyze`).  ``scalar``
-#: is the node-by-node Python oracle; ``numpy`` and ``jax`` run the
-#: lowered level-propagation of :mod:`repro.core.sta_vec`, bit-identical
-#: to it (the sampled-delay ``rng`` mode always falls back to scalar).
-STA_BACKENDS = ("scalar", "numpy", "jax")
-
-
-def sta_backend(default: str = "scalar") -> str:
-    """Default timing-analysis backend (``CASCADE_STA_BACKEND``).
-
-    Driver-side only, exactly like :func:`pnr_backend`: benchmark CLIs
-    and examples copy the value into ``PassConfig.sta_backend`` (or the
-    ``backend=`` argument of :func:`repro.core.sta.analyze`) — the
-    library never reads the env var implicitly, keeping cache keys
-    faithful.  An unknown value warns and falls back to ``default``.
-    """
-    v = os.environ.get("CASCADE_STA_BACKEND")
-    if v is None or not v.strip():
-        return default
-    v = v.strip().lower()
-    if v not in STA_BACKENDS:
-        warnings.warn(
-            f"ignoring unknown CASCADE_STA_BACKEND={v!r} "
-            f"(expected one of {STA_BACKENDS}); falling back to "
-            f"{default!r}", UserWarning, stacklevel=2)
-        return default
-    return v
+#: is the node-by-node Python oracle; ``numpy`` runs the lowered
+#: level-propagation of :mod:`repro.core.sta_vec`, bit-identical to it
+#: (the sampled-delay ``rng`` mode always falls back to scalar).
+STA_BACKENDS = ("scalar", "numpy")
 
 
 def pnr_backend(default: str = "numpy") -> str:

@@ -74,7 +74,7 @@ def evaluate_design(design: RoutedDesign, tm: TimingModel,
     states return bit-equal numbers, which is what lets the power-cap
     controller and the frontier sweep promise byte-identity with the
     report passes.  ``sta_backend`` selects the timing engine
-    (``scalar`` / ``numpy`` / ``jax`` — bit-identical, see
+    (``scalar`` / ``numpy`` — bit-identical, see
     :mod:`repro.core.sta_vec`).
     """
     rep = rep if rep is not None else analyze(design, tm,

@@ -22,8 +22,8 @@ The inner loop is vectorized: net terminals live in a padded
 terminal, which leaves the bounding-box extremes unchanged), so one move
 re-scores all its touched nets with a handful of numpy reductions instead
 of per-net Python dict churn.  Move proposals and acceptance draws are
-pre-drawn in per-temperature blocks; the scalar fallback
-(``vectorized=False``) consumes the identical RNG stream and computes
+pre-drawn in per-temperature blocks; the scalar reference
+(``backend="scalar"``) consumes the identical RNG stream and computes
 bit-identical per-net costs, so both modes produce byte-identical
 placements for the same seed.
 
@@ -58,11 +58,11 @@ class PlaceParams:
     moves_per_node: int = 400 # total move budget = moves_per_node * n
     t_factor: float = 0.92
     restarts: int = 1
-    vectorized: bool = True   # batched net-cost evaluation (same results)
     debug: Optional[bool] = None   # None -> CASCADE_PLACE_DEBUG env flag
     resync_tol: float = 1e-6  # drift tolerance for the debug assertions
-    # kernel backend: None resolves to "numpy"/"scalar" from ``vectorized``
-    # (back-compat); "jax" runs the jitted parallel-tempering annealer in
+    # kernel backend: None means "numpy" (batched net-cost evaluation);
+    # "scalar" is its bit-identical per-net reference; "jax" runs the
+    # jitted parallel-tempering annealer in
     # :mod:`repro.core.place_jax` (``replicas`` chains on a geometric
     # temperature ladder, spread ``replica_spread`` apart, exchanging
     # states after every temperature step; ``restarts`` is subsumed by the
@@ -76,7 +76,7 @@ class PlaceParams:
     proposal_block: int = 32  # jax: move proposals evaluated per step
 
     def resolved_backend(self) -> str:
-        b = self.backend or ("numpy" if self.vectorized else "scalar")
+        b = self.backend or "numpy"
         if b not in PNR_BACKENDS:
             raise ValueError(
                 f"unknown place backend {b!r}; expected one of "
@@ -332,7 +332,6 @@ def place(nl: Netlist, fabric: Fabric,
     if stats is not None:
         stats.update({
             "backend": backend,
-            "vectorized": vectorized,
             **extra,
             "nodes": n, "nets": len(nets.nets),
             "moves_evaluated": moves_evaluated,

@@ -26,7 +26,7 @@ from typing import Callable, Dict, List, Optional, Set, Tuple
 from ..runtime.spans import span
 from .branch_delay import MatchPlan
 from .netlist import RoutedDesign
-from .sta import STAReport, analyze
+from .sta import STAReport, analyze, check_backend
 from .timing_model import TimingModel
 
 
@@ -213,10 +213,11 @@ class _ScalarEngine:
 
 def _make_engine(design: RoutedDesign, tm: TimingModel, sta_backend: str,
                  lowering=None):
+    check_backend(sta_backend)
     if sta_backend == "scalar":
         return _ScalarEngine(design, tm)
     from .sta_vec import IncrementalSTA
-    return IncrementalSTA(design, tm, backend=sta_backend, lowering=lowering)
+    return IncrementalSTA(design, tm, lowering=lowering)
 
 
 def _analyze(engine) -> STAReport:
@@ -233,11 +234,11 @@ def post_pnr_pipeline(design: RoutedDesign, tm: TimingModel,
     """The Section V-D register-insertion loop.
 
     ``sta_backend`` selects the timing engine: ``"scalar"`` re-walks the
-    netlist every round (the oracle); ``"numpy"`` / ``"jax"`` keep a
+    netlist every round (the oracle); ``"numpy"`` keeps a
     :class:`~repro.core.sta_vec.IncrementalSTA` alive across rounds, so
     each insertion re-propagates only the dirty fanout cone of the edited
     hops (optionally reusing a caller-supplied ``lowering`` of the routed
-    structure).  All backends produce byte-identical designs, histories,
+    structure).  Both backends produce byte-identical designs, histories,
     and stop reasons — one shared loop drives an engine seam, so the
     control flow cannot drift, and the engines' reports are bit-identical
     by construction (asserted in tests and benchmarks).

@@ -17,9 +17,7 @@ Both accept a ``backend`` argument (``"interpreter"`` / ``"numpy"`` /
 ``"jax"``, default interpreter): the vectorized backends in
 :mod:`repro.core.sim_vec` lower the graph once to tensor form and are
 bit-identical to the interpreter over the 16-bit value domain — see that
-module and :func:`repro.core.config.sim_backend` for the
-``CASCADE_SIM_BACKEND`` seam (mirrors ``pnr_backend`` from PR 6: drivers
-read the env var, library code only ever takes the explicit argument).
+module.
 
 The interpreter is also the *oracle for predicated execution*: edges in
 the ``[PRED_PORT, CONTROL_PORT)`` band resolve to the consuming node's

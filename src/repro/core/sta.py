@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .config import STA_BACKENDS
 from .dfg import FIFO, INPUT, MEM, OUTPUT, PE, RF
 from .netlist import RoutedBranch, RoutedDesign
 from .timing_model import TimingModel
@@ -80,20 +81,28 @@ class _Sampler:
         return self.cache[key]
 
 
+def check_backend(backend: str) -> None:
+    """Refuse a timing engine that is not one of :data:`STA_BACKENDS`."""
+    if backend not in STA_BACKENDS:
+        raise ValueError(f"unknown STA backend {backend!r}; "
+                         f"expected one of {STA_BACKENDS}")
+
+
 def analyze(design: RoutedDesign, tm: TimingModel,
             rng: Optional[np.random.Generator] = None,
             sigma_lo: float = 0.6,
             clock_granularity_ns: float = 0.0,
             backend: str = "scalar") -> STAReport:
     """Application STA.  ``backend`` selects the engine: ``"scalar"`` is
-    this module's node-by-node walk (the oracle); ``"numpy"`` / ``"jax"``
-    run the lowered whole-level propagation of :mod:`repro.core.sta_vec`,
+    this module's node-by-node walk (the oracle); ``"numpy"`` runs the
+    lowered whole-level propagation of :mod:`repro.core.sta_vec`,
     bit-identical to it.  The sampled-delay path (``rng``) draws one
     factor per component *instance* in visit order, so it always runs on
     the scalar walk regardless of ``backend``."""
+    check_backend(backend)
     if backend != "scalar" and rng is None:
         from .sta_vec import analyze_vec
-        return analyze_vec(design, tm, backend=backend,
+        return analyze_vec(design, tm,
                            clock_granularity_ns=clock_granularity_ns)
     nl, fabric = design.netlist, design.fabric
     sample = _Sampler(rng, sigma_lo)
@@ -227,71 +236,3 @@ def sdf_simulate_fmax(design: RoutedDesign, tm: TimingModel, seed: int = 0,
         worst_cp = max(worst_cp, rep.critical_path_ns)
     period = math.ceil(worst_cp / granularity_ns) * granularity_ns
     return 1e3 / period
-
-
-# ---------------------------------------------------------------------------
-# max-plus formulation (TPU-friendly; backed by the Pallas kernel)
-# ---------------------------------------------------------------------------
-
-def timing_matrix(design: RoutedDesign, tm: TimingModel) -> Tuple[np.ndarray, List[str]]:
-    """Dense max-plus adjacency of the *combinational segment* graph.
-
-    M[i, j] = delay of the combinational edge j -> i (NEG_INF if none).
-    Longest path = max-plus fixpoint of ``arr = M (x) arr``; used by the JAX /
-    Pallas backend (kernels/maxplus) and exercised by the kernel tests against
-    this numpy construction.
-    """
-    NEG = np.float32(-1e9)
-    nl, fabric = design.netlist, design.fabric
-    verts: List[str] = []
-
-    def vid(key) -> int:
-        s = str(key)
-        if s not in vindex:
-            vindex[s] = len(verts)
-            verts.append(s)
-        return vindex[s]
-
-    vindex: Dict[str, int] = {}
-    edges: List[Tuple[int, int, float]] = []
-    for name, node in nl.nodes.items():
-        core = tm.core_delay("io" if node.kind in (INPUT, OUTPUT)
-                             else node.kind, node.op)
-        iv, ov = vid(("in", name)), vid(("out", name))
-        if _seq_output(node):
-            edges.append((vid("SRC"), ov, tm.reg_clk_q + core))
-        else:
-            edges.append((iv, ov, core))
-    for rb in design.routes.values():
-        b = rb.branch
-        prev = vid(("out", b.driver))
-        acc = 0.0
-        for i, hop in enumerate(rb.hops):
-            acc += tm.hop_delay(fabric, hop)
-            if i in rb.reg_hops:
-                hv = vid(("hop", b.key, i))
-                edges.append((prev, hv, acc))
-                edges.append((vid("SRC"), hv, 0.0))  # also a launch point
-                # capture side handled by reading arrival at hv
-                prev, acc = hv, tm.reg_clk_q
-                # new segment launches from the register
-        edges.append((prev, vid(("in", b.sink)), acc + tm.cb_in))
-    n = len(verts)
-    M = np.full((n, n), NEG, dtype=np.float32)
-    for u, v, d in edges:
-        M[v, u] = max(M[v, u], np.float32(d))
-    return M, verts
-
-
-def longest_path_maxplus(M: np.ndarray, src: int = 0) -> np.ndarray:
-    """Reference max-plus longest-path (numpy); O(V^2 * diameter)."""
-    NEG = np.float32(-1e9)
-    n = M.shape[0]
-    arr = np.full((n,), NEG, dtype=np.float32)
-    arr[src] = 0.0
-    for _ in range(n):
-        nxt = np.maximum(arr, (M + arr[None, :]).max(axis=1))
-        if np.allclose(nxt, arr):
-            break
-        arr = nxt
-    return arr

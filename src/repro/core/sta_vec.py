@@ -14,10 +14,7 @@ Pareto-frontier sweep.  This module removes the per-round Python walk:
   a boolean mask — so one lowering serves every pipelining state of the
   design (and every deep-copied fork the explorer makes, which is why
   frontier points share one).
-* arrival propagation runs level by level as whole-array gathers
-  (numpy) or as one jitted ``lax.scan`` over padded levels (jax, under
-  ``jax.enable_x64`` so float64 arithmetic matches the oracle bit for bit;
-  CPU only, since a TPU's emulated float64 does not).
+* arrival propagation runs level by level as whole-array numpy gathers.
 * :class:`IncrementalSTA` keeps the arrival vector alive across
   pipelining rounds: a register insertion only flips mask bits, so each
   re-analyze re-propagates just the dirty fanout cone of the edited
@@ -39,7 +36,6 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from functools import lru_cache
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -62,8 +58,8 @@ class LoweredSTA:
     *register occupancy* is the caller's boolean site mask, so the same
     lowering re-times every pipelining state of the design.  Pure
     numpy + dicts — picklable, so the batch explorer can ship one
-    lowering to pool workers (the lazily-built jax executable is
-    dropped on pickle and rebuilt on first use).
+    lowering to pool workers (the lazily-built list mirrors are dropped
+    on pickle and rebuilt on first use).
     """
 
     n_verts: int
@@ -113,12 +109,10 @@ class LoweredSTA:
     site_delay: np.ndarray                # hop delay per site (candidates)
     core_of: Dict[str, float]             # node -> core delay (candidates)
 
-    _jax: dict = field(default_factory=dict, repr=False, compare=False)
     _scalar: dict = field(default_factory=dict, repr=False, compare=False)
 
     def __getstate__(self):
         state = dict(self.__dict__)
-        state["_jax"] = {}                # device buffers don't pickle
         state["_scalar"] = {}             # cheap to rebuild on first use
         return state
 
@@ -179,24 +173,6 @@ class LoweredSTA:
                 m = np.maximum.reduceat(arr[esrc], eoff)
                 arr[v] = np.maximum(m, 0.0) + core
         return arr
-
-    def propagate_jax(self, mask: np.ndarray) -> np.ndarray:
-        import jax
-
-        if jax.default_backend() != "cpu":
-            # a TPU emulates float64: its max/add do not round like the
-            # oracle's, and the critical path moves in the last bits
-            raise RuntimeError(
-                f"STA backend 'jax' runs only on the CPU: on "
-                f"{jax.default_backend()!r} its float64 arrivals differ "
-                f"from the scalar oracle; use 'numpy'")
-        st = self._jax
-        if not st:
-            st.update(_jax_state(self))
-        with jax.enable_x64(True):
-            arr = st["fn"](st["consts"], jax_mask(mask))
-        out = np.asarray(arr, dtype=np.float64)[:self.n_verts]
-        return out
 
     # -- incremental propagation ------------------------------------------
     def propagate_incremental(self, arr: np.ndarray, mask: np.ndarray,
@@ -538,135 +514,25 @@ def lower_design(design: RoutedDesign, tm: TimingModel) -> LoweredSTA:
 
 
 # ---------------------------------------------------------------------------
-# jax backend: one jitted lax.scan over padded levels
-# ---------------------------------------------------------------------------
-
-def jax_mask(mask: np.ndarray):
-    import jax.numpy as jnp
-    return jnp.asarray(mask)   # sentinel slot already included
-
-
-def _pad2(rows: List[np.ndarray], width: int, fill: int) -> np.ndarray:
-    out = np.full((len(rows), max(1, width)), fill, dtype=np.int64)
-    for i, r in enumerate(rows):
-        out[i, :len(r)] = r
-    return out
-
-
-def _pad2f(rows: List[np.ndarray], width: int) -> np.ndarray:
-    out = np.zeros((len(rows), max(1, width)), dtype=np.float64)
-    for i, r in enumerate(rows):
-        out[i, :len(r)] = r
-    return out
-
-
-def _jax_state(L: LoweredSTA) -> dict:
-    """Build the padded level tensors + the jitted propagation callable.
-
-    The sentinel vertex ``n_verts`` absorbs every padded read/write; the
-    sentinel site ``n_sites`` reads an always-False mask slot.  Per-level
-    scatter order is irrelevant: every predecessor lives at a strictly
-    smaller level, so there are no intra-level dependencies.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    sent = L.n_verts
-    sp_v, sp_p, sp_s, sp_d = [], [], [], []
-    mp_v, mp_c, me_d, me_s = [], [], [], []
-    for lv in range(1, L.n_levels):
-        sp = L.lvl_sp[lv]
-        sp_v.append(sp[0] if sp else np.empty(0, np.int64))
-        sp_p.append(sp[1] if sp else np.empty(0, np.int64))
-        site = sp[2] if sp else np.empty(0, np.int64)
-        sp_s.append(np.where(site < 0, L.n_sites, site))  # -1 -> sentinel
-        sp_d.append(sp[3] if sp else np.empty(0, np.float64))
-        mp = L.lvl_mp[lv]
-        if mp:
-            v, core, esrc, eoff = mp
-            mp_v.append(v)
-            mp_c.append(core)
-            dst = np.repeat(v, np.diff(np.append(eoff, len(esrc))))
-            me_d.append(dst)
-            me_s.append(esrc)
-        else:
-            mp_v.append(np.empty(0, np.int64))
-            mp_c.append(np.empty(0, np.float64))
-            me_d.append(np.empty(0, np.int64))
-            me_s.append(np.empty(0, np.int64))
-
-    w1 = max((len(r) for r in sp_v), default=0)
-    w2 = max((len(r) for r in mp_v), default=0)
-    w3 = max((len(r) for r in me_d), default=0)
-    with jax.enable_x64(True):
-        consts = (
-            jnp.asarray(_pad2(sp_v, w1, sent)), jnp.asarray(_pad2(sp_p, w1, sent)),
-            jnp.asarray(_pad2(sp_s, w1, L.n_sites)), jnp.asarray(_pad2f(sp_d, w1)),
-            jnp.asarray(_pad2(mp_v, w2, sent)), jnp.asarray(_pad2f(mp_c, w2)),
-            jnp.asarray(_pad2(me_d, w3, sent)), jnp.asarray(_pad2(me_s, w3, sent)),
-            jnp.asarray(np.append(
-                np.where(L.vp_kind == _CONST, L.vp_const, 0.0), 0.0)),
-            jnp.asarray(np.float64(L.reg_clk_q)),
-        )
-    fn = _jitted_propagate(L.n_verts, L.n_levels)
-    return {"consts": consts, "fn": fn}
-
-
-@lru_cache(maxsize=64)
-def _jitted_propagate(n_verts: int, n_levels: int):
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    def run(consts, mask):
-        (sp_v, sp_p, sp_s, sp_d, mp_v, mp_c, me_d, me_s, init, rq) = consts
-        arr0 = init  # length n_verts + 1 (sentinel)
-
-        def step(arr, xs):
-            v, p, s, d, mv, mc, md, ms = xs
-            base = arr[p]
-            gated = mask[s]
-            arr = arr.at[v].set(jnp.where(gated, rq, base) + d)
-            arr = arr.at[mv].set(0.0)
-            arr = arr.at[md].max(arr[ms])
-            arr = arr.at[mv].set(arr[mv] + mc)
-            return arr, None
-
-        arr, _ = lax.scan(step, arr0,
-                          (sp_v, sp_p, sp_s, sp_d, mp_v, mp_c, me_d, me_s))
-        return arr
-
-    return jax.jit(run)
-
-
-# ---------------------------------------------------------------------------
 # the incremental engine + one-shot entry point
 # ---------------------------------------------------------------------------
 
 class IncrementalSTA:
     """Arrival-time state kept alive across pipelining rounds.
 
-    ``numpy``: the arrival vector is materialized once, then every
-    :meth:`analyze` re-propagates only the dirty fanout cone of the
-    register sites flipped since the last call.  ``jax``: each analyze
-    re-runs the whole jitted level scan (one warm XLA dispatch — the
-    incremental bookkeeping would cost more than it saves).
-    Reports are bit-identical to :func:`repro.core.sta.analyze` in
-    either mode.
+    The arrival vector is materialized once, then every :meth:`analyze`
+    re-propagates only the dirty fanout cone of the register sites
+    flipped since the last call.  Reports are bit-identical to
+    :func:`repro.core.sta.analyze`.
     """
 
     def __init__(self, design: RoutedDesign, tm: TimingModel,
-                 backend: str = "numpy",
                  lowering: Optional[LoweredSTA] = None):
-        if backend not in ("numpy", "jax"):
-            raise ValueError(f"unknown STA engine backend {backend!r}")
         self.design = design
-        self.backend = backend
         self.L = lowering if lowering is not None else lower_design(design, tm)
         self.mask = self.L.initial_mask(design)
         self._dirty: set = set()
-        self.arr = (self.L.propagate_numpy(self.mask)
-                    if backend == "numpy" else None)
+        self.arr = self.L.propagate_numpy(self.mask)
 
     # -- mask maintenance --------------------------------------------------
     def _flip(self, sites, value: bool) -> None:
@@ -702,10 +568,7 @@ class IncrementalSTA:
         """Current-state report.  ``arrival_out`` is omitted by default —
         the pipelining loop never reads it per round; pass
         ``with_arrivals=True`` for a full report."""
-        if self.backend == "jax":
-            self.arr = self.L.propagate_jax(self.mask)
-            self._dirty.clear()
-        elif self._dirty:
+        if self._dirty:
             self.L.propagate_incremental(self.arr, self.mask, list(self._dirty))
             self._dirty.clear()
         return self.L.report(self.arr, self.mask, clock_granularity_ns,
@@ -758,7 +621,6 @@ class IncrementalSTA:
 
 
 def analyze_vec(design: RoutedDesign, tm: TimingModel,
-                backend: str = "numpy",
                 clock_granularity_ns: float = 0.0,
                 lowering: Optional[LoweredSTA] = None) -> STAReport:
     """One-shot vectorized STA: lower (or reuse ``lowering``), propagate,
@@ -767,11 +629,4 @@ def analyze_vec(design: RoutedDesign, tm: TimingModel,
     same routed structure."""
     L = lowering if lowering is not None else lower_design(design, tm)
     mask = L.initial_mask(design)
-    if backend == "numpy":
-        arr = L.propagate_numpy(mask)
-    elif backend == "jax":
-        arr = L.propagate_jax(mask)
-    else:
-        raise ValueError(f"unknown STA backend {backend!r}; "
-                         f"expected 'numpy' or 'jax'")
-    return L.report(arr, mask, clock_granularity_ns)
+    return L.report(L.propagate_numpy(mask), mask, clock_granularity_ns)

@@ -1,6 +1,5 @@
 """Pallas TPU kernels (validated against jnp oracles):
 
-  maxplus         tropical matmul — the STA longest-path fixpoint
   stencil         3x3 window pipelines — the dense CGRA benchmarks' compute
   flash_attention blocked online-softmax attention (prefill/train)
   flash_decode    single-token cache attention (the serving memory wall)

@@ -7,62 +7,8 @@ import pytest
 
 from repro.kernels.flash_attention import (attention_ref, flash_attention,
                                            gqa_attention)
-from repro.kernels.maxplus import (longest_path, longest_path_ref,
-                                   maxplus_matmul, maxplus_matmul_ref)
 from repro.kernels.stencil import (GAUSS3, SHARPEN3, SOBEL_X3, gaussian_blur,
                                    stencil3x3, stencil3x3_ref)
-
-
-# ---------------------------------------------------------------------------
-# maxplus
-
-
-@pytest.mark.parametrize("m,k,n", [(8, 8, 8), (100, 130, 70), (128, 128, 128),
-                                   (200, 50, 300), (1, 257, 1)])
-@pytest.mark.parametrize("dtype", ["float32"])
-def test_maxplus_matmul_shapes(m, k, n, dtype):
-    rng = np.random.default_rng(m * 1000 + k * 10 + n)
-    a = jnp.asarray(rng.normal(size=(m, k)).astype(dtype))
-    b = jnp.asarray(rng.normal(size=(k, n)).astype(dtype))
-    np.testing.assert_allclose(maxplus_matmul(a, b),
-                               maxplus_matmul_ref(a, b), rtol=1e-6, atol=1e-6)
-
-
-@pytest.mark.parametrize("bm,bn,bk", [(128, 128, 128), (64, 128, 32)])
-def test_maxplus_block_shapes(bm, bn, bk):
-    rng = np.random.default_rng(0)
-    a = jnp.asarray(rng.normal(size=(150, 90)).astype("float32"))
-    b = jnp.asarray(rng.normal(size=(90, 60)).astype("float32"))
-    got = maxplus_matmul(a, b, bm=bm, bn=bn, bk=bk)
-    np.testing.assert_allclose(got, maxplus_matmul_ref(a, b), rtol=1e-6)
-
-
-@pytest.mark.parametrize("n,edges,seed", [(20, 40, 0), (64, 200, 1),
-                                          (130, 400, 2)])
-def test_longest_path_random_dag(n, edges, seed):
-    rng = np.random.default_rng(seed)
-    m = np.full((n, n), -1e9, np.float32)
-    for _ in range(edges):
-        i, j = sorted(rng.integers(0, n, 2))
-        if i != j:
-            m[j, i] = max(m[j, i], float(rng.uniform(0.05, 3.0)))
-    got = longest_path(jnp.asarray(m))
-    want = longest_path_ref(jnp.asarray(m))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
-
-
-def test_longest_path_matches_cascade_sta():
-    """The max-plus kernel agrees with the compiler's own STA numbers."""
-    from repro.core.apps import ALL_APPS
-    from repro.core.compiler import CascadeCompiler, PassConfig
-    from repro.core.sta import longest_path_maxplus, timing_matrix
-
-    c = CascadeCompiler()
-    r = c.compile(ALL_APPS["gaussian"], PassConfig.full(place_moves=40))
-    m, verts = timing_matrix(r.design, c.timing)
-    ref = longest_path_maxplus(m)
-    got = np.asarray(longest_path(jnp.asarray(m)))
-    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-3)
 
 
 # ---------------------------------------------------------------------------

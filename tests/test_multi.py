@@ -58,14 +58,14 @@ def test_subregion_masks_tiles_and_neighbors():
 REGIONS = [Region(0, 0, 32, 8), Region(0, 8, 32, 8), Region(0, 4, 16, 8)]
 
 
-@pytest.mark.parametrize("vectorized", [True, False])
+@pytest.mark.parametrize("backend", ["numpy", "scalar"])
 @pytest.mark.parametrize("region", REGIONS)
-def test_no_placed_node_or_routed_hop_leaves_region(vectorized, region):
+def test_no_placed_node_or_routed_hop_leaves_region(backend, region):
     """Property: for both SA kernel paths, every placed node and every hop
     of every routed branch stays inside the app's region."""
     fabric = Fabric()
     nl = extract_netlist(ALL_APPS["vecadd"].build(1))
-    pp = PlaceParams(seed=1, moves_per_node=20, vectorized=vectorized)
+    pp = PlaceParams(seed=1, moves_per_node=20, backend=backend)
     placement = place(nl, fabric, pp, region=region)
     assert all(region.contains(t) for t in placement.values())
     design = route(nl, placement, fabric.subregion(region), region=region)
@@ -79,9 +79,9 @@ def test_scalar_and_vectorized_region_placements_identical():
     nl = extract_netlist(ALL_APPS["unsharp"].build(1))
     region = Region(0, 0, 32, 8)
     a = place(nl, fabric, PlaceParams(seed=3, moves_per_node=20,
-                                      vectorized=True), region=region)
+                                      backend="numpy"), region=region)
     b = place(nl, fabric, PlaceParams(seed=3, moves_per_node=20,
-                                      vectorized=False), region=region)
+                                      backend="scalar"), region=region)
     assert a == b
 
 

@@ -70,16 +70,17 @@ def test_vectorized_place_matches_scalar_place_bitwise():
     nl = extract_netlist(g)
     fab = Fabric()
     placements, stats = {}, {}
-    for mode in (True, False):
+    for mode in ("numpy", "scalar"):
         st = {}
         placements[mode] = place(
             nl, fab, PlaceParams(alpha=1.6, seed=3, moves_per_node=40,
-                                 vectorized=mode), stats=st)
+                                 backend=mode), stats=st)
         stats[mode] = st
-    assert placements[True] == placements[False]
-    assert stats[True]["best_cost"] == stats[False]["best_cost"]   # bitwise
-    assert stats[True]["moves_accepted"] == stats[False]["moves_accepted"]
-    assert stats[True]["vectorized"] and not stats[False]["vectorized"]
+    assert placements["numpy"] == placements["scalar"]
+    assert stats["numpy"]["best_cost"] == stats["scalar"]["best_cost"]  # bitwise
+    assert stats["numpy"]["moves_accepted"] == stats["scalar"]["moves_accepted"]
+    assert stats["numpy"]["backend"] == "numpy"
+    assert stats["scalar"]["backend"] == "scalar"
 
 
 def test_place_debug_resync_passes_and_counts():
@@ -97,7 +98,8 @@ def test_place_stats_surface_in_pass_stats():
     r = CascadeCompiler(cache=CompileCache()).compile(
         ALL_APPS["unsharp"], PassConfig.full(place_moves=20))
     ps = r.pass_stats["pnr"]["place"]
-    assert ps["vectorized"] and r.pass_stats["pass_times"]["place"] > 0
+    assert ps["backend"] == "numpy"
+    assert r.pass_stats["pass_times"]["place"] > 0
     assert ps["nodes"] > 0 and ps["nets"] > 0
 
 

@@ -2,8 +2,6 @@
 oracle, lowering guards, deadlock diagnostics, and the reference-stream
 memo behind ``equivalent``/``sparse_equivalent``."""
 
-import warnings
-
 import numpy as np
 import pytest
 
@@ -12,7 +10,7 @@ from test_cascade_core import _inputs_for, random_dfg, random_pred_dfg
 
 from repro.core import (DENSE_APPS, SPARSE_APPS, SIM_BACKENDS,
                         SimLoweringError, clear_ref_memo, equivalent,
-                        lower_dense, sim_backend, simulate, simulate_sparse,
+                        lower_dense, simulate, simulate_sparse,
                         sparse_equivalent)
 from repro.core.dfg import DFG, INPUT, MEM, OUTPUT, PE
 from repro.core.pipelining import compute_pipelining
@@ -235,19 +233,3 @@ def test_equivalent_memoizes_reference_streams():
     clear_ref_memo()
     assert ref_memo_stats == {"hits": 0, "misses": 0}
 
-
-# ---------------------------------------------------------------------------
-# CASCADE_SIM_BACKEND seam (driver-side env knob)
-# ---------------------------------------------------------------------------
-
-
-def test_sim_backend_env_seam(monkeypatch):
-    monkeypatch.delenv("CASCADE_SIM_BACKEND", raising=False)
-    assert sim_backend() == "interpreter"
-    monkeypatch.setenv("CASCADE_SIM_BACKEND", "jax")
-    assert sim_backend() == "jax"
-    monkeypatch.setenv("CASCADE_SIM_BACKEND", "verilator")
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert sim_backend() == "interpreter"
-    assert any("CASCADE_SIM_BACKEND" in str(x.message) for x in w)

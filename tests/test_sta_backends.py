@@ -1,20 +1,19 @@
-"""Vectorized STA backends (``repro.core.sta_vec``).
+"""The numpy STA engine (``repro.core.sta_vec``) against the scalar oracle.
 
-Bit-identity of the scalar oracle with the numpy / jax lowered engines —
+Bit-identity of the scalar oracle with the lowered numpy engine —
 critical path ns, path reconstruction, arrival maps, segment counts — on
-real routed designs and on randomized register states (hypothesis, via
-the ``_hypothesis_compat`` shim); byte-identity of the incremental
-post-PnR pipelining loop across backends (histories, stop reasons,
+every app's routed design and on randomized register states (hypothesis,
+via the ``_hypothesis_compat`` shim); byte-identity of the incremental
+post-PnR pipelining loop across engines (histories, stop reasons,
 register placements) including the budget, round-hook, and power-cap
 stop paths; the ``(driver, sink)`` route index vs the reference scan;
-the ``sta_backend`` stage-key seam; and the ``CASCADE_STA_BACKEND``
-driver knob.
+the refusal of unknown engine names; and the ``sta_backend`` stage-key
+seam.
 """
 
 import copy
 import pickle
 import random
-import warnings
 
 import numpy as np
 import pytest
@@ -23,22 +22,17 @@ from _hypothesis_compat import given, settings, st
 from repro.core import (ALL_APPS, CascadeCompiler, CompileCache, PassConfig,
                         PostPnRParams, analyze, analyze_vec, lower_design,
                         post_pnr_pipeline, power_capped_pipeline, stage_key,
-                        sta_backend, STA_BACKENDS)
+                        STA_BACKENDS)
 from repro.core.passes import DEFAULT_SCHEDULE
 from repro.core.post_pnr import _find_branch
 from repro.core.sta_vec import IncrementalSTA
 
-try:
-    import jax  # noqa: F401
-    HAVE_JAX = True
-except Exception:                        # pragma: no cover - env dependent
-    HAVE_JAX = False
+#: the lowered engine under test beside the scalar oracle
+VEC_BACKENDS = ("numpy",)
 
-#: vector engines under test (jax rides along when importable)
-VEC_BACKENDS = ("numpy",) + (("jax",) if HAVE_JAX else ())
-
-#: (app, unroll) design points — dense and sparse, unrolled and not
-APPS = (("gaussian", 1), ("harris", 1), ("mttkrp", 2))
+#: (app, unroll) design points — every app: dense, sparse and predicated,
+#: with mttkrp unrolled
+APPS = tuple((name, 2 if name == "mttkrp" else 1) for name in ALL_APPS)
 
 _COMPILER = None
 _ROUTED = {}
@@ -90,7 +84,7 @@ def test_backends_bit_identical_on_routed_designs(app, unroll, backend):
     ref = analyze(design, tm)
     _assert_reports_identical(ref, analyze(design, tm, backend=backend))
     # the sta_vec entry point and the analyze() dispatch agree too
-    _assert_reports_identical(ref, analyze_vec(design, tm, backend=backend))
+    _assert_reports_identical(ref, analyze_vec(design, tm))
 
 
 @pytest.mark.parametrize("backend", VEC_BACKENDS)
@@ -121,24 +115,23 @@ def test_sampled_delay_path_stays_on_scalar_walk():
 
 
 def test_unknown_vec_backend_rejected():
+    """Both entry points refuse a name outside ``STA_BACKENDS`` — the
+    one-shot ``analyze`` (also on its sampled-delay path) and the
+    pipelining loop's engine — and so does a compile configured with it."""
+    assert STA_BACKENDS == ("scalar", "numpy")
     design, tm = _routed("gaussian", 1)
+    for backend in ("jax", "torch"):
+        with pytest.raises(ValueError, match="unknown STA backend"):
+            analyze(design, tm, backend=backend)
+        with pytest.raises(ValueError, match="'scalar', 'numpy'"):
+            analyze(design, tm, backend=backend,
+                    rng=np.random.default_rng(0))
+        with pytest.raises(ValueError, match="unknown STA backend"):
+            post_pnr_pipeline(copy.deepcopy(design), tm,
+                              sta_backend=backend)
     with pytest.raises(ValueError, match="unknown STA backend"):
-        analyze_vec(design, tm, backend="torch")
-    with pytest.raises(ValueError, match="unknown STA engine backend"):
-        IncrementalSTA(design, tm, backend="torch")
-
-
-def test_jax_backend_refused_off_the_cpu(monkeypatch):
-    """A TPU emulates float64 and its arrivals differ from the oracle's in
-    the last bits: the jax STA refuses to run there rather than report a
-    different critical path."""
-    import jax
-    design, tm = _routed("gaussian", 1)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(RuntimeError, match="runs only on the CPU"):
-        analyze(design, tm, backend="jax")
-    with pytest.raises(RuntimeError, match="runs only on the CPU"):
-        IncrementalSTA(design, tm, backend="jax").analyze()
+        _compiler().compile(ALL_APPS["gaussian"],
+                            PassConfig(sta_backend="jax"))
 
 
 # ---------------------------------------------------------------------------
@@ -208,7 +201,7 @@ def test_random_reg_states_seeded_sweep():
 def test_incremental_engine_tracks_mutations(backend):
     design, tm = _routed("harris", 1)
     d = copy.deepcopy(design)
-    eng = IncrementalSTA(d, tm, backend=backend)
+    eng = IncrementalSTA(d, tm)
     rng = random.Random(11)
     added = []
     for rb in d.routes.values():
@@ -242,7 +235,7 @@ def test_lowering_is_shared_and_picklable():
     # one lowering serves a deepcopied fork (structure is shared)
     fork = copy.deepcopy(design)
     _assert_reports_identical(ref, analyze_vec(fork, tm, lowering=L))
-    # pickles (jax executables / scalar mirrors are dropped), still exact
+    # pickles (the scalar mirrors are dropped), still exact
     L2 = pickle.loads(pickle.dumps(L))
     _assert_reports_identical(ref, analyze_vec(design, tm, lowering=L2))
 
@@ -388,22 +381,3 @@ def test_backend_field_reuses_routed_artifacts_end_to_end():
     assert r_s.sta.critical_path_ns == r_n.sta.critical_path_ns
     assert r_s.power.scaled() == r_n.power.scaled()
 
-
-# ---------------------------------------------------------------------------
-# CASCADE_STA_BACKEND seam (driver-side env knob)
-# ---------------------------------------------------------------------------
-
-
-def test_sta_backend_env_seam(monkeypatch):
-    monkeypatch.delenv("CASCADE_STA_BACKEND", raising=False)
-    assert sta_backend() == "scalar"
-    monkeypatch.setenv("CASCADE_STA_BACKEND", "numpy")
-    assert sta_backend() == "numpy"
-    monkeypatch.setenv("CASCADE_STA_BACKEND", "jax")
-    assert sta_backend() == "jax"
-    monkeypatch.setenv("CASCADE_STA_BACKEND", "verilator")
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        assert sta_backend() == "scalar"
-    assert any("CASCADE_STA_BACKEND" in str(x.message) for x in w)
-    assert set(STA_BACKENDS) == {"scalar", "numpy", "jax"}
